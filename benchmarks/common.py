@@ -3,16 +3,12 @@ model used by the accuracy benchmarks (Table 1 / Fig. 9 proxies)."""
 
 from __future__ import annotations
 
-import dataclasses
-import os
 import time
 from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-_CACHE = os.environ.get("REPRO_BENCH_CACHE", "/tmp/repro_bench_cache")
 
 
 def timeit(fn: Callable, *args, n: int = 3, warmup: int = 1) -> float:

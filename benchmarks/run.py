@@ -15,6 +15,8 @@ from .common import Csv
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from . import (decode_bench, drift, failover, fig3_dot_error,
                    fig4_overflow, fig5_markov, fig9_pareto, kernel_bench,
                    replica_throughput, roofline_table, serving_bench,

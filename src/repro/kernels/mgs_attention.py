@@ -72,10 +72,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import E4M3, FPFormat, encode_bits
+from repro.parallel.sharding import replicated_kernel_call
 
-from .mgs_matmul import (_CompilerParams, _decode_limbs, _limb_split,
-                         _N_CLASSES, _N_LIMBS, _LIMB_BASE,
-                         _round_decompose_e4m3)
+from .mgs_matmul import (_decode_limbs, _limb_split, _N_CLASSES, _N_LIMBS,
+                         _LIMB_BASE, _round_decompose_e4m3,
+                         default_interpret)
 
 __all__ = ["mgs_flash_attention", "mgs_flash_attention_ref",
            "mgs_paged_flash_attention", "mgs_paged_verify_attention",
@@ -166,14 +167,23 @@ def _pairwise_sum_cols(x):
     halving tree of elementwise adds whose association order is fixed by
     the graph, so the softmax denominator is identical on every mesh and
     on both the kernel and reference paths.
+
+    Level ``s`` adds each column to the one ``s`` lanes to its right
+    (a lane rotate, which Mosaic lowers; the strided ``x[..., 0::2]``
+    halving it replaces lowers to a gather Mosaic refuses). Column 0
+    then holds ``(x0 + x1) + (x2 + x3) ...``: the halving tree's exact
+    association, so the sum is bit-for-bit what the halving tree gave.
+    Wrapped-around columns are never read.
     """
     n = x.shape[-1]
     p = 1 << max(0, (n - 1).bit_length())
     if p != n:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p - n)])
-    while x.shape[-1] > 1:
-        x = x[..., 0::2] + x[..., 1::2]
-    return x
+    s = 1
+    while s < p:
+        x = x + jnp.roll(x, -s, axis=-1)
+        s *= 2
+    return x[..., :1]
 
 
 def _attn_tile_step(lq, k_codes, v_codes, qk_row, v_row, bias, m, l, o,
@@ -330,7 +340,7 @@ def _flash_pallas(q_codes, k_pool, v_pool, bt, live, qk_scale, v_scale,
         functools.partial(_flash_kernel, nsteps=nb, chunk=chunk, fmt=fmt),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, T, D), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(bt, live, last, q_codes, k_pool, v_pool, qk_scale, v_scale, bias)
@@ -380,7 +390,7 @@ def _flash_ref(q_codes, k_pool, v_pool, bt, live, qk_scale, v_scale, bias,
 def _dispatch(q_codes, k_pool, v_pool, bt, live, qk_scale, v_scale, bias,
               fmt: FPFormat, use_kernel: bool, interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     if k_pool.shape[1] > flash_chunk_limit():
         raise ValueError(f"chunk {k_pool.shape[1]} exceeds the int32 "
                          f"class-accumulator bound {flash_chunk_limit()}")
@@ -394,8 +404,9 @@ def _dispatch(q_codes, k_pool, v_pool, bt, live, qk_scale, v_scale, bias,
         qk_scale.shape, q_codes.shape)
     live = live.astype(jnp.int32)
     if use_kernel:
-        return _flash_pallas(q_codes, k_pool, v_pool, bt, live, qk_scale,
-                             v_scale, bias, fmt, interpret)
+        return replicated_kernel_call(
+            lambda *a: _flash_pallas(*a, fmt, interpret), q_codes, k_pool,
+            v_pool, bt, live, qk_scale, v_scale, bias)
     return _flash_ref(q_codes, k_pool, v_pool, bt, live, qk_scale,
                       v_scale, bias, fmt)
 

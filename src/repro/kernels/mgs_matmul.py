@@ -65,15 +65,18 @@ from repro.core.formats import E4M3, FPFormat
 
 __all__ = ["mgs_matmul_exact_pallas", "mgs_matmul_exact_fused_pallas",
            "mgs_matmul_dmac_pallas", "limb_decompose",
-           "worst_case_flush_period", "ACTIVATIONS"]
+           "worst_case_flush_period", "default_interpret", "ACTIVATIONS"]
 
 _LIMB_BASE = 7
 _N_LIMBS = 3
 _N_CLASSES = 2 * _N_LIMBS - 1  # limb-weight classes a+b in [0, 4]
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+
+def default_interpret() -> bool:
+    """Interpret-mode default for every kernel entry point: Mosaic on a
+    TPU backend, the Pallas interpreter anywhere else (CPU tests)."""
+    return jax.default_backend() != "tpu"
+
 
 # Epilogue activations the fused kernel can apply in-VMEM. Must match the
 # model-layer definitions (models.common) bit-for-bit so fusing an
@@ -270,7 +273,7 @@ def mgs_matmul_exact_pallas(x, w, fmt: FPFormat = E4M3, *, block_m: int = 128,
             pltpu.VMEM((_N_CLASSES, block_m, block_n), jnp.int32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lx, lw, fp)
@@ -532,7 +535,7 @@ def mgs_matmul_exact_fused_pallas(x_codes, w_codes, fmt: FPFormat = E4M3, *,
                 pltpu.VMEM((_N_CLASSES, block_m, block_n), jnp.int32),
                 pltpu.VMEM((block_m, block_n), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(xc, wc, srow, brow, fp)
@@ -553,7 +556,7 @@ def mgs_matmul_exact_fused_pallas(x_codes, w_codes, fmt: FPFormat = E4M3, *,
             pltpu.VMEM((_N_CLASSES, block_m, block_n), jnp.int32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, wc, srow, brow, fp)
@@ -650,7 +653,7 @@ def mgs_matmul_dmac_pallas(x, w, fmt: FPFormat = E4M3,
         scratch_shapes=[
             pltpu.VMEM((fmt.n_bins, block_m, block_n), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp)

@@ -27,9 +27,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.formats import E4M3, FPFormat, encode_bits
+from repro.parallel.sharding import replicated_kernel_call
 from . import mgs_matmul as _mm
 from . import ref as _ref
-from .mgs_matmul import (ACTIVATIONS, mgs_matmul_dmac_pallas,
+from .mgs_matmul import (ACTIVATIONS, default_interpret,
+                         mgs_matmul_dmac_pallas,
                          mgs_matmul_exact_fused_pallas,
                          mgs_matmul_exact_pallas, ws_stripe_bytes)
 
@@ -39,10 +41,6 @@ __all__ = ["mgs_matmul", "apply_epilogue"]
 # tile in VMEM; tiles beyond this budget cannot fit alongside the bin
 # accumulators on real TPUs (~16 MB VMEM/core).
 _DMAC_TILE_BUDGET_BYTES = 2 << 20
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _dmac_block_shapes(block_m: int, block_n: int, block_k: int):
@@ -136,7 +134,7 @@ def mgs_matmul(x, w, fmt: FPFormat = E4M3, mode: str = "exact", *,
     oversized stationary stripes fall back to "output" with a warning.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     prepared = hasattr(w, "codes") and hasattr(w, "limbs")
     ix_bits = fmt.mbits + 1 + fmt.emax  # fixed-point width of sm << e
     if mode == "exact" and ix_bits > 21:
@@ -166,30 +164,33 @@ def mgs_matmul(x, w, fmt: FPFormat = E4M3, mode: str = "exact", *,
     elif mode == "exact" and fused:
         xc = x2 if x2.dtype == jnp.uint8 else encode_bits(x2, fmt)
         wc = w.codes if prepared else encode_bits(w, fmt)
-        out = mgs_matmul_exact_fused_pallas(
-            xc, wc, fmt, scale=scale, bias=bias, activation=activation,
-            block_m=block_m, block_n=block_n, block_k=block_k,
-            flush_period=flush_period,
-            schedule=_fused_schedule(schedule, K, block_m, block_n,
-                                     block_k),
-            interpret=interpret)
+        sched = _fused_schedule(schedule, K, block_m, block_n, block_k)
+        out = replicated_kernel_call(
+            lambda xc, wc, s, b, fp: mgs_matmul_exact_fused_pallas(
+                xc, wc, fmt, scale=s, bias=b, activation=activation,
+                block_m=block_m, block_n=block_n, block_k=block_k,
+                flush_period=fp, schedule=sched, interpret=interpret),
+            xc, wc, scale, bias, flush_period)
     elif mode == "exact":
         # prepared weights without resident limb planes (built for a fused
         # config) fall back to decoding values from the packed codes
         w_limbs = w.limbs if prepared else None
         w_vals = None if w_limbs is not None else (
             w.values() if prepared else w)
-        out = mgs_matmul_exact_pallas(
-            x2, w_vals, fmt, w_limbs=w_limbs,
-            block_m=block_m, block_n=block_n, block_k=block_k,
-            flush_period=flush_period, interpret=interpret)
+        out = replicated_kernel_call(
+            lambda x2, wv, wl, fp: mgs_matmul_exact_pallas(
+                x2, wv, fmt, w_limbs=wl, block_m=block_m, block_n=block_n,
+                block_k=block_k, flush_period=fp, interpret=interpret),
+            x2, w_vals, w_limbs, flush_period)
         out = apply_epilogue(out, scale, bias, activation)
     elif mode == "dmac":
         bm, bn = _dmac_block_shapes(block_m, block_n, block_k)
         w_vals = w.values() if prepared else w
-        out = mgs_matmul_dmac_pallas(
-            x2, w_vals, fmt, gate_subnormal, block_m=bm, block_n=bn,
-            block_k=block_k, interpret=interpret)
+        out = replicated_kernel_call(
+            lambda x2, wv: mgs_matmul_dmac_pallas(
+                x2, wv, fmt, gate_subnormal, block_m=bm, block_n=bn,
+                block_k=block_k, interpret=interpret),
+            x2, w_vals)
     else:
         raise ValueError(f"unknown mode {mode!r}")
     return out.reshape(lead + (n_out,))

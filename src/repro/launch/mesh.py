@@ -20,12 +20,8 @@ __all__ = ["make_production_mesh", "make_mesh", "make_serve_mesh",
 
 
 def _mesh(shape, axes):
-    # jax >= 0.5 wants explicit axis_types; 0.4.x has no AxisType at all.
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -105,10 +101,12 @@ def carve_submeshes(replicas: int, *, model_parallel: int | None = None,
     for r in range(replicas):
         grid = np.asarray(devs[r * per:(r + 1) * per],
                           dtype=object).reshape(per // mp, mp)
-        # jax.sharding.Mesh (not jax.make_mesh): make_mesh has no explicit
-        # device list on the jax versions this repo supports, and the
-        # default Auto axis types match _mesh's behaviour.
-        meshes.append(jax.sharding.Mesh(grid, ("data", "model")))
+        # jax.sharding.Mesh keeps the carved device order exactly
+        # (jax.make_mesh may permute a device list to the physical
+        # topology)
+        meshes.append(jax.sharding.Mesh(
+            grid, ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2))
     return meshes
 
 

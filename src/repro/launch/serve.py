@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs import get_config, reduced_config
 from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_mesh, make_serve_mesh
 from repro.models import (adopt_slot, decode_step, decode_step_paged,
                           draft_step_paged, init_cache, init_paged_cache,
@@ -1279,6 +1280,7 @@ def main():
                          "with --replicas > 1 (replica engines are "
                          "deterministic by construction)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.replicas > 1 and args.no_deterministic:
         ap.error("--no-deterministic is incompatible with --replicas > 1: "
                  "the replica driver exists to provide data-parallel "

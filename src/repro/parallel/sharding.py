@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["Rules", "TRAIN_RULES", "make_rules", "train_rules", "use_rules",
            "constrain", "resolve_spec", "current_rules", "named_sharding",
-           "prepared_plane_dims", "prepared_specs"]
+           "prepared_plane_dims", "prepared_specs", "replicated_kernel_call"]
 
 
 class Rules:
@@ -212,6 +212,26 @@ def use_rules(rules: Optional[Rules]):
         yield rules
     finally:
         _ctx.rules = prev
+
+
+def replicated_kernel_call(fn, *args):
+    """Run one Pallas kernel call whole on every device of the active mesh.
+
+    GSPMD cannot partition a Mosaic kernel: on a TPU mesh of more than
+    one device XLA refuses a bare kernel call. Under such a mesh the call
+    goes through a ``shard_map`` whose specs are all replicated: sharded
+    operands are gathered, each device runs the full call, and the
+    result is replicated — exactly the one-device result, so the
+    cross-mesh bit-identity contract holds. Weight planes stay sharded
+    at rest; kernel compute does not scale with the mesh. ``args`` may
+    hold arrays, ``None`` and Python scalars; ``fn`` closes over static
+    values only.
+    """
+    rules = current_rules()
+    if rules is None or rules.mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=rules.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 def constrain(x, dims: Tuple[Optional[str], ...]):

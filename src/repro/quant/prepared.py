@@ -180,22 +180,23 @@ def _build(w, cfg: QuantConfig, stack_ndim: int, k_ndim: int,
             return codes, limbs, qt.scale, sigma
         return codes, qt.scale, sigma
 
-    limbs = None
+    # one jitted program per weight: its float/int32 temporaries are
+    # fused away, so building a plane never holds more than the raw
+    # weight plus the outputs (run op by op, a stacked 7B-width FFN
+    # weight's temporaries alone are several GB of device memory)
+    jit_kw = {}
     if shardings is not None:
         # build straight into the mesh layout: the planes come out of the
         # jit already sharded — never materialized replicated-then-moved.
         codes_sh, limbs_sh, scale_sh = shardings
-        if keep_limbs:
-            out_sh = (codes_sh, limbs_sh, scale_sh, None)
-            codes, limbs, scale, sigma = jax.jit(
-                compute, out_shardings=out_sh)(w)
-        else:
-            codes, scale, sigma = jax.jit(
-                compute, out_shardings=(codes_sh, scale_sh, None))(w)
-    elif keep_limbs:
-        codes, limbs, scale, sigma = compute(w)
+        jit_kw["out_shardings"] = ((codes_sh, limbs_sh, scale_sh, None)
+                                   if keep_limbs
+                                   else (codes_sh, scale_sh, None))
+    limbs = None
+    if keep_limbs:
+        codes, limbs, scale, sigma = jax.jit(compute, **jit_kw)(w)
     else:
-        codes, scale, sigma = compute(w)
+        codes, scale, sigma = jax.jit(compute, **jit_kw)(w)
     PREP_STATS["prepared"] += 1
     return PreparedWeight(codes, limbs, scale, fmt.name, tuple(tail),
                           float(sigma))

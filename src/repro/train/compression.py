@@ -18,7 +18,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["init_error_state", "compress_leaf_psum",
@@ -70,8 +69,8 @@ def make_compressed_reduce(mesh: Mesh, data_axes: Tuple[str, ...]):
     def apply(grads, err):
         specs_g = jax.tree.map(lambda _: P(), grads)
         specs_e = jax.tree.map(lambda _: P(), err)
-        fn = shard_map(body, mesh=mesh, in_specs=(specs_g, specs_e),
-                       out_specs=(specs_g, specs_e), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(specs_g, specs_e),
+                           out_specs=(specs_g, specs_e), check_vma=False)
         return fn(grads, err)
 
     return apply
