@@ -19,8 +19,8 @@ def main() -> None:
     configure_compile_cache()
     from . import (decode_bench, drift, failover, fig3_dot_error,
                    fig4_overflow, fig5_markov, fig9_pareto, kernel_bench,
-                   replica_throughput, roofline_table, serving_bench,
-                   spec_bench, table1_accuracy, table3_energy)
+                   replica_throughput, roofline_table, spec_bench,
+                   table1_accuracy, table3_energy)
     suites = {
         "fig3": fig3_dot_error.run,
         "fig4": fig4_overflow.run,
@@ -34,7 +34,6 @@ def main() -> None:
         "decode": decode_bench.run,
         "failover": failover.run,
         "drift": drift.run,
-        "serving": serving_bench.run,
         "spec": spec_bench.run,
     }
     want = sys.argv[1:] or list(suites)

@@ -55,11 +55,6 @@ python -m benchmarks.run decode
 # BENCH_failover.json.
 python -m benchmarks.run failover
 
-# Serving-benchmark smoke (ISSUE-7): seeded Poisson ragged traffic,
-# continuous batching vs fixed groups — p50/p99 latency + tok/s;
-# refreshes BENCH_serving.json.
-python -m benchmarks.run serving
-
 # Speculative-decoding smoke (ISSUE-8): sequential vs draft/verify
 # rounds on the same burst, asserting bitwise-equal tokens per row;
 # the fast sweep keeps CI short — the full sweep (python -m

@@ -21,7 +21,7 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,7 @@ from repro.quant import (BlockAllocator, PreparedWeight, calibrating,
                          prepare_logits_head, prepare_params)
 from repro.quant.calibrate import CalibrationTable, applied_calib_state
 from repro.quant.streaming import StreamingCalibrator, sample_gate
+from repro.runtime import spans
 
 __all__ = ["ServeEngine", "ContinuousBatchingEngine", "Request",
            "bucket_for", "make_engine", "main"]
@@ -751,8 +752,6 @@ class _Slot:
     """Book-keeping for one occupied decode slot (host-side only)."""
     req: Request
     blocks: List[int]
-    arrival: float
-    admit_s: float
     cur: int                       # token to feed at the next decode step
 
 
@@ -937,9 +936,17 @@ class ContinuousBatchingEngine(ServeEngine):
         cs["q_amax"] = jnp.asarray(self._slot_amax)
         return cs
 
-    def _admit(self, req: Request, arrival: float, t0: float,
-               active: Dict[int, _Slot]) -> Optional[_Slot]:
-        """Try to admit one request; None if no slot/blocks right now."""
+    def _span(self, name: str, rid=None, **attrs):
+        """A host span of this engine (:mod:`repro.runtime.spans`)."""
+        return spans.span(name, rid, engine=id(self), **attrs)
+
+    def _admit(self, req: Request, due: float, active: Dict[int, _Slot],
+               tries: int) -> Optional[_Slot]:
+        """Try to admit one request; None if no slot/blocks right now.
+
+        An admission is the span ``serve.admit`` (prefill, adoption and
+        the first token); the request's wait from ``due`` (host clock) to
+        it is ``serve.queue``, ``tries`` the rounds it found no room."""
         plen = len(req.prompt)
         bucket = bucket_for(plen, self._buckets, block=self.block_size)
         # reserve spec_k - 1 extra rows: a verify round starting at the
@@ -956,35 +963,37 @@ class ContinuousBatchingEngine(ServeEngine):
                 f"table width {self.n_table} (raise max_len)")
         if not self._free_slots or self.alloc.n_free < n_alloc:
             return None
-        slot = self._free_slots.popleft()
-        blocks = self.alloc.alloc(n_alloc)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, bucket - plen:] = req.prompt          # left-pad
-        if self._streaming is not None and not self._replaying:
-            idx = self._stream_index
-            self._stream_index += 1
-            if sample_gate(self._stream_seed, idx,
-                           self._streaming.sample_period):
-                self._shadow_pass(toks)
-        with self._calib_lock:
-            # admission-time pin: version stamp, per-slot q amax, and
-            # the state the prefill runs under are one consistent read
-            req.table_version = self.table_version
-            self._slot_amax[slot] = self._amax_value
-            cs = self._cs()
-        pcache, _ = init_cache(self.cfg, 1, bucket)
-        logits, pcache = self._prefill(self.params, self._make_batch(toks),
-                                       pcache, cs)
-        phys = np.zeros(self.n_table, np.int32)       # tail -> trash block
-        phys[:n_alloc] = blocks
-        self.cache = self._adopt(self.cache, pcache,
-                                 jnp.asarray(slot, jnp.int32),
-                                 jnp.asarray(phys))
-        tok = int(jnp.argmax(logits[0]))
-        st = _Slot(req=req, blocks=blocks, arrival=arrival,
-                   admit_s=time.monotonic() - t0, cur=tok)
-        active[slot] = st
-        self._harvest(slot, st, active, np.asarray(logits[0]))
+        with self._span("serve.admit", req.rid, bucket=bucket) as span:
+            slot = self._free_slots.popleft()
+            blocks = self.alloc.alloc(n_alloc)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, bucket - plen:] = req.prompt          # left-pad
+            if self._streaming is not None and not self._replaying:
+                idx = self._stream_index
+                self._stream_index += 1
+                if sample_gate(self._stream_seed, idx,
+                               self._streaming.sample_period):
+                    self._shadow_pass(toks)
+            with self._calib_lock:
+                # admission-time pin: version stamp, per-slot q amax, and
+                # the state the prefill runs under are one consistent read
+                req.table_version = self.table_version
+                self._slot_amax[slot] = self._amax_value
+                cs = self._cs()
+            pcache, _ = init_cache(self.cfg, 1, bucket)
+            logits, pcache = self._prefill(self.params, self._make_batch(toks),
+                                           pcache, cs)
+            phys = np.zeros(self.n_table, np.int32)       # tail -> trash block
+            phys[:n_alloc] = blocks
+            self.cache = self._adopt(self.cache, pcache,
+                                     jnp.asarray(slot, jnp.int32),
+                                     jnp.asarray(phys))
+            tok = int(jnp.argmax(logits[0]))
+            st = _Slot(req=req, blocks=blocks, cur=tok)
+            active[slot] = st
+            self._harvest(slot, st, active, np.asarray(logits[0]))
+        spans.record("serve.queue", due, span.start, req.rid, tries=tries,
+                     engine=id(self))
         return st
 
     def _harvest(self, slot: int, st: _Slot, active: Dict[int, _Slot],
@@ -1004,6 +1013,47 @@ class ContinuousBatchingEngine(ServeEngine):
             self._cur[slot, 0] = 0
             self._slot_amax[slot] = 0.0
             del active[slot]
+
+    def _spec_step(self, active: Dict[int, _Slot], finish) -> Tuple[int, int]:
+        """One speculative round of the active slots: draft, verify,
+        accept, harvest and rewind. Returns (drafted, accepted)."""
+        k = self.spec_k
+        # one fused launch drafts and verifies the whole round; a single
+        # host sync covers all k positions
+        tokens, logits, self.cache = self._spec_round(
+            self.params, jnp.asarray(self._cur), self.cache,
+            self._cs_decode())
+        targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with self._span("serve.wait"):
+            jax.block_until_ready((tokens, logits, targets))
+        with self._span("serve.readback"):
+            targets = np.asarray(targets)
+            tokens_np = np.asarray(tokens)
+            rows = np.asarray(logits)      # (slots, k, vocab)
+        drafted = accepted = 0
+        with self._span("serve.harvest"):
+            keep = np.zeros(self.slots, np.int32)
+            for slot in list(active):
+                st = active[slot]
+                # exact acceptance: drafts survive while they equal the
+                # verify argmax at their position
+                a = 0
+                while (a + 1 < k
+                       and tokens_np[slot, a + 1] == targets[slot, a]):
+                    a += 1
+                drafted += k - 1
+                accepted += a
+                keep[slot] = a + 1
+                for j in range(a + 1):
+                    st.cur = int(targets[slot, j])
+                    self._harvest(slot, st, active, rows[slot, j])
+                    if st.req.done:
+                        finish(st.req)
+                        break
+            # released slots have pos == 0 and are skipped; live ones
+            # advance by their accepted count and shed the rejected rows
+            self.cache = self._rewind(self.cache, jnp.asarray(keep))
+        return drafted, accepted
 
     def serve(self, requests: List[Request], *, arrivals=None,
               record_logits: bool = False, feed=None,
@@ -1030,10 +1080,16 @@ class ContinuousBatchingEngine(ServeEngine):
 
         Returns the :meth:`ServeEngine.run`-style stats dict plus
         ``steps`` (decode steps run — speculative *rounds* when
-        ``spec_k`` is set, each emitting 1..k tokens), per-request
-        ``timing[rid] = (arrival_s, admit_s, done_s)``, and — under
+        ``spec_k`` is set, each emitting 1..k tokens), and — under
         speculation — ``stats["spec"]`` with the round's drafted /
         accepted counts and acceptance rate.
+
+        Timing goes to :mod:`repro.runtime.spans`: each scheduling round
+        is a ``serve.round`` span holding ``serve.feed`` (the ``feed``
+        call), ``serve.admit`` per admission (with the request's
+        ``serve.queue`` wait before it), and, when it decodes,
+        ``serve.wait`` (the device step), ``serve.readback`` (logits to
+        the host) and ``serve.harvest`` (argmax, token append, release).
         """
         if arrivals is None:
             arrivals = [0.0] * len(requests)
@@ -1044,105 +1100,83 @@ class ContinuousBatchingEngine(ServeEngine):
         t0 = time.monotonic()
         waiting = deque(zip(arrivals, requests))
         active: Dict[int, _Slot] = {}
-        timing: Dict[int, Any] = {}
         n_prefill = n_decode = n_steps = 0
         n_drafted = n_accepted = 0
+        tries = 0               # rounds the queue's head found no room
         self._serving = True
 
-        def finish(req: Request, arrival: float, admit_s: float):
+        def finish(req: Request):
             nonlocal n_decode
             n_decode += len(req.out_tokens)
-            timing[req.rid] = (arrival, admit_s, time.monotonic() - t0)
             if on_done is not None:
                 on_done(req)
 
         try:
             with use_rules(self.rules):
                 while True:
-                    now = time.monotonic() - t0
-                    if feed is not None:
-                        for req in feed():
-                            waiting.append((now, req))
-                    if (self._pending is not None and not active
-                            and not self._replaying):
-                        # fenced hot swap: the active slots drained, install
-                        # the deferred table and resume admissions under it
-                        ServeEngine.apply_calibration(self, self._pending)
-                        self._pending = None
-                    while (waiting and waiting[0][0] <= now
-                           and (self._pending is None or self._replaying)):
-                        arr, req = waiting[0]
-                        st = self._admit(req, arr, t0, active)
-                        if st is None:
+                    with self._span("serve.round"):
+                        now = time.monotonic() - t0
+                        if feed is not None:
+                            with self._span("serve.feed"):
+                                fed = feed()
+                            for req in fed:
+                                waiting.append((now, req))
+                        if (self._pending is not None and not active
+                                and not self._replaying):
+                            # fenced hot swap: the active slots drained,
+                            # install the deferred table and resume
+                            # admissions under it
+                            ServeEngine.apply_calibration(self, self._pending)
+                            self._pending = None
+                        while (waiting and waiting[0][0] <= now
+                               and (self._pending is None or self._replaying)):
+                            arr, req = waiting[0]
+                            st = self._admit(req, t0 + arr, active, tries)
+                            if st is None:
+                                tries += 1
+                                break
+                            tries = 0
+                            waiting.popleft()
+                            n_prefill += bucket_for(len(req.prompt),
+                                                    self._buckets,
+                                                    block=self.block_size)
+                            if req.done:                  # done at first token
+                                finish(req)
+                        if not active:
+                            if waiting:
+                                time.sleep(min(1e-3, max(0.0,
+                                                         waiting[0][0] - now)))
+                                continue
                             break
-                        waiting.popleft()
-                        n_prefill += bucket_for(len(req.prompt), self._buckets,
-                                                block=self.block_size)
-                        if req.done:                      # done at first token
-                            finish(req, arr, st.admit_s)
-                    if not active:
-                        if waiting:
-                            time.sleep(min(1e-3, max(0.0,
-                                                     waiting[0][0] - now)))
-                            continue
-                        break
-                    for slot, st in active.items():
-                        self._cur[slot, 0] = st.cur
-                    if self.spec_k:
-                        k = self.spec_k
-                        # one fused launch drafts and verifies the whole
-                        # round; a single host sync covers all k positions
-                        tokens, logits, self.cache = self._spec_round(
-                            self.params, jnp.asarray(self._cur), self.cache,
-                            self._cs_decode())
+                        for slot, st in active.items():
+                            self._cur[slot, 0] = st.cur
                         n_steps += 1
-                        targets = np.asarray(
-                            jnp.argmax(logits, axis=-1).astype(jnp.int32))
-                        tokens_np = np.asarray(tokens)
-                        rows = np.asarray(logits)      # (slots, k, vocab)
-                        keep = np.zeros(self.slots, np.int32)
-                        for slot in list(active):
-                            st = active[slot]
-                            # exact acceptance: drafts survive while they
-                            # equal the verify argmax at their position
-                            a = 0
-                            while (a + 1 < k and tokens_np[slot, a + 1]
-                                    == targets[slot, a]):
-                                a += 1
-                            n_drafted += k - 1
-                            n_accepted += a
-                            keep[slot] = a + 1
-                            for j in range(a + 1):
-                                st.cur = int(targets[slot, j])
-                                self._harvest(slot, st, active, rows[slot, j])
-                                if st.req.done:
-                                    finish(st.req, st.arrival, st.admit_s)
-                                    break
-                        # released slots have pos == 0 and are skipped; live
-                        # ones advance by their accepted count and shed the
-                        # rejected rows
-                        self.cache = self._rewind(self.cache,
-                                                  jnp.asarray(keep))
-                    else:
-                        logits, self.cache = self._decode_paged(
-                            self.params, jnp.asarray(self._cur), self.cache,
-                            self._cs_decode())
-                        n_steps += 1
-                        rows = np.asarray(logits)
-                        for slot in list(active):
-                            st = active[slot]
-                            st.cur = int(rows[slot].argmax())
-                            self._harvest(slot, st, active, rows[slot])
-                            if st.req.done:
-                                finish(st.req, st.arrival, st.admit_s)
+                        if self.spec_k:
+                            drafted, accepted = self._spec_step(active, finish)
+                            n_drafted += drafted
+                            n_accepted += accepted
+                        else:
+                            logits, self.cache = self._decode_paged(
+                                self.params, jnp.asarray(self._cur),
+                                self.cache, self._cs_decode())
+                            with self._span("serve.wait"):
+                                logits.block_until_ready()
+                            with self._span("serve.readback"):
+                                rows = np.asarray(logits)
+                            with self._span("serve.harvest"):
+                                for slot in list(active):
+                                    st = active[slot]
+                                    st.cur = int(rows[slot].argmax())
+                                    self._harvest(slot, st, active, rows[slot])
+                                    if st.req.done:
+                                        finish(st.req)
         finally:
             self._serving = False
         dt = time.monotonic() - t0
         stats: Dict[str, Any] = {
             "prefill_tokens": n_prefill, "decode_tokens": n_decode,
             "steps": n_steps, "wall_s": dt,
-            "decode_tok_per_s": n_decode / max(dt, 1e-9),
-            "timing": timing}
+            "decode_tok_per_s": n_decode / max(dt, 1e-9)}
         if self.spec_k:
             stats["spec"] = {
                 "k": self.spec_k,

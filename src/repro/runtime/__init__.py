@@ -1,3 +1,3 @@
-from . import checkpoint, elastic, fault_tolerance
+from . import checkpoint, elastic, fault_tolerance, spans
 
-__all__ = ["checkpoint", "elastic", "fault_tolerance"]
+__all__ = ["checkpoint", "elastic", "fault_tolerance", "spans"]
