@@ -20,6 +20,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.mgs_attention import (mgs_flash_attention,
                                          mgs_paged_flash_attention,
                                          mgs_paged_verify_attention)
+from repro.parallel.sharding import constrain, on_multi_device_mesh
 from repro.quant import (PagedKVCache, QuantizedKVCache, append_kv,
                          paged_append_kv, qeinsum)
 from repro.quant.quantize import QTensor, quantize_fp8, quantize_fp8_static
@@ -323,16 +324,58 @@ def _sdpa_packed_cache(q, cache: QuantizedKVCache, bias, quant,
         q.dtype)
 
 
+def _paged_operands(cache: PagedKVCache, block_table, layer):
+    """Layer ``layer``'s paged-kernel operands from the stacked pool.
+
+    ``cache`` planes are the stacked ``(La, P, KV, bs, hd)`` pool the
+    paged steps carry through their layer scan. On one device the
+    kernel reads the whole pool in place: ``(La * P * KV, bs, hd)`` is a
+    pure reshape, and layer ``l``'s block ``p`` head ``h`` is tile
+    ``(l * P + p) * KV + h``, so no layer slice is ever materialized.
+    A multi-device mesh gathers every kernel operand
+    (:func:`repro.parallel.sharding.replicated_kernel_call`), so there
+    the one layer is sliced out first, still sharded, rather than
+    gathering ``La`` times the bytes. Either way the kernel sees the
+    same bytes at the same tiles.
+
+    Returns ``(k_tiles, v_tiles, tile_ids (B*KV, nb), k_scale_rows,
+    v_scale_rows)``; the scale rows are gathered into logical
+    ``(B*KV, nb * bs)`` order (~1/hd of the code bytes), because they
+    fold into the per-key score/value multipliers before the launch.
+    """
+    P, KV, bs, hd = cache.k_codes.shape[1:]
+    if on_multi_device_mesh():
+        dims = ("blocks", "kv_heads", "block", "head_dim")
+        cache = PagedKVCache(*(constrain(jax.lax.dynamic_index_in_dim(
+            plane, layer, keepdims=False), dims[:plane.ndim - 1])
+            for plane in cache))
+        base = 0
+    else:
+        base = layer * P
+    tiles = block_table.astype(jnp.int32) + base
+    B, nb = tiles.shape
+
+    def rows(scale):
+        r = jnp.take(scale.reshape(-1, KV, bs), tiles.reshape(-1), axis=0)
+        return r.reshape(B, nb, KV, bs).transpose(0, 2, 1, 3).reshape(
+            B * KV, nb * bs)
+
+    tile_ids = (tiles[:, None, :] * KV
+                + jnp.arange(KV, dtype=jnp.int32)[None, :, None]).reshape(
+                    B * KV, nb)
+    return (cache.k_codes.reshape(-1, bs, hd),
+            cache.v_codes.reshape(-1, bs, hd), tile_ids,
+            rows(cache.k_scale), rows(cache.v_scale))
+
+
 def _sdpa_paged_cache(q, cache: PagedKVCache, block_table, bias, lengths,
-                      quant):
+                      quant, layer):
     """Decode attention over the paged pool: the block-table MGS kernel.
 
     The paged twin of :func:`_sdpa_packed_cache`. Codes never move — the
     kernel (:func:`repro.kernels.mgs_attention.mgs_paged_flash_attention`)
-    walks each slot's blocks through a scalar-prefetched table, and only
-    the per-entry *scale rows* (~1/head_dim of the code bytes) are
-    gathered into logical (B*KV, S) order here, because they fold into
-    the per-key score/value multipliers before the kernel launch.
+    walks each slot's blocks of layer ``layer`` of the stacked pool
+    through a scalar-prefetched table (:func:`_paged_operands`).
     ``lengths`` are the per-slot live key counts (0 = free slot: that
     row's every chunk is gated off and its output is exactly zero).
     Per-slice query scales + per-entry cache scales + the gated walk
@@ -340,9 +383,7 @@ def _sdpa_paged_cache(q, cache: PagedKVCache, block_table, bias, lengths,
     the continuous-batching invariance contract.
     """
     B, T, KV, G, hd = q.shape
-    bs = cache.k_codes.shape[2]
-    nb = block_table.shape[1]
-    S = nb * bs
+    S = block_table.shape[1] * cache.k_codes.shape[-2]
     fmt = quant.kv_fmt
     q2 = q.transpose(0, 2, 3, 1, 4).reshape(B * KV, G * T * hd)
     qt = _quantize_decode_q(q2, quant, batch=B)
@@ -350,20 +391,8 @@ def _sdpa_paged_cache(q, cache: PagedKVCache, block_table, bias, lengths,
     if quant.accum in ("mgs_exact", "mgs_dmac"):
         from repro.quant.calibrate import observe
         observe("attn.scores", qvals, fmt)
-    bt = block_table.astype(jnp.int32)
-    ks = jnp.take(cache.k_scale, bt.reshape(-1), axis=0)
-    vs = jnp.take(cache.v_scale, bt.reshape(-1), axis=0)
-    ks = ks.reshape(B, nb, KV, bs).transpose(0, 2, 1, 3).reshape(B * KV, S)
-    vs = vs.reshape(B, nb, KV, bs).transpose(0, 2, 1, 3).reshape(B * KV, S)
+    kp, vp, bt_nk, ks, vs = _paged_operands(cache, block_table, layer)
     qk = (qt.scale * ks) * (hd ** -0.5)
-    # pool view (P, KV, bs, hd) -> (P*KV, bs, hd) is a pure reshape;
-    # slot b / head h / chunk j lives in physical tile bt[b, j]*KV + h
-    P = cache.k_codes.shape[0]
-    kp = cache.k_codes.reshape(P * KV, bs, hd)
-    vp = cache.v_codes.reshape(P * KV, bs, hd)
-    bt_nk = (bt[:, None, :] * KV
-             + jnp.arange(KV, dtype=jnp.int32)[None, :, None]).reshape(
-                 B * KV, nb)
     live = jnp.repeat(lengths.astype(jnp.int32), KV)
     bias2 = jnp.broadcast_to(bias.reshape(B, 1, S), (B, KV, S)).reshape(
         B * KV, S)
@@ -375,7 +404,7 @@ def _sdpa_paged_cache(q, cache: PagedKVCache, block_table, bias, lengths,
 
 
 def _sdpa_paged_verify(q, cache: PagedKVCache, block_table, bias,
-                       positions, lengths, quant):
+                       positions, lengths, quant, layer):
     """Multi-query (T > 1) verify attention over the paged pool.
 
     The speculative verify step's twin of :func:`_sdpa_paged_cache`.
@@ -393,9 +422,7 @@ def _sdpa_paged_verify(q, cache: PagedKVCache, block_table, bias,
     gated to 0 for dead slots (``lengths == 0``).
     """
     B, T, KV, G, hd = q.shape
-    bs = cache.k_codes.shape[2]
-    nb = block_table.shape[1]
-    S = nb * bs
+    S = block_table.shape[1] * cache.k_codes.shape[-2]
     fmt = quant.kv_fmt
     # (B, T, KV, G, hd) -> (B*KV*T, G*hd) rows, token-fastest — the
     # sequential decode step's per-slice quantization granularity
@@ -405,19 +432,9 @@ def _sdpa_paged_verify(q, cache: PagedKVCache, block_table, bias,
     if quant.accum in ("mgs_exact", "mgs_dmac"):
         from repro.quant.calibrate import observe
         observe("attn.scores", qvals, fmt)
-    bt = block_table.astype(jnp.int32)
-    ks = jnp.take(cache.k_scale, bt.reshape(-1), axis=0)
-    vs = jnp.take(cache.v_scale, bt.reshape(-1), axis=0)
-    ks = ks.reshape(B, nb, KV, bs).transpose(0, 2, 1, 3).reshape(B * KV, S)
-    vs = vs.reshape(B, nb, KV, bs).transpose(0, 2, 1, 3).reshape(B * KV, S)
+    kp, vp, bt_nk, ks, vs = _paged_operands(cache, block_table, layer)
     qk = qt.scale.reshape(B * KV, T, 1) * ks[:, None, :] * (hd ** -0.5)
     vs3 = jnp.broadcast_to(vs[:, None, :], (B * KV, T, S))
-    P = cache.k_codes.shape[0]
-    kp = cache.k_codes.reshape(P * KV, bs, hd)
-    vp = cache.v_codes.reshape(P * KV, bs, hd)
-    bt_nk = (bt[:, None, :] * KV
-             + jnp.arange(KV, dtype=jnp.int32)[None, :, None]).reshape(
-                 B * KV, nb)
     # per-token causal horizons: token t's live keys end at positions+1
     live_t = jnp.where(lengths[:, None] > 0,
                        positions.astype(jnp.int32) + 1, 0)
@@ -436,7 +453,8 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions,
                     cache: Optional[KVCache] = None,
                     cache_pos=None,
                     cross_kv: Optional[KVCache] = None,
-                    kv_positions=None, block_table=None, lengths=None):
+                    kv_positions=None, block_table=None, lengths=None,
+                    layer=None):
     """Self- or cross-attention.
 
     x: (B, T, d). positions: (B, T) int32 token positions of the queries.
@@ -447,10 +465,12 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions,
     attends the cache *codes* through the MGS flash-decode kernel
     (:mod:`repro.kernels.mgs_attention`); prefill (T > 1) attends the
     freshly-projected float K/V and only *stores* them quantized. With
-    the paged pool (decode-only), ``cache_pos`` is a per-slot ``(B,)``
-    position vector, ``block_table`` ``(B, nb)`` names each slot's
-    physical blocks and ``lengths`` ``(B,)`` its live key count
-    (0 = free slot).
+    the paged pool (decode-only), ``cache`` is the stacked
+    ``(La, P, KV, bs, hd)`` pool and ``layer`` the traced index of this
+    layer in it, ``cache_pos`` is a per-slot ``(B,)`` position vector,
+    ``block_table`` ``(B, nb)`` names each slot's physical blocks and
+    ``lengths`` ``(B,)`` its live key count (0 = free slot); the whole
+    stacked pool comes back with this layer's entries appended.
     cross_kv: precomputed encoder K/V (whisper decoder) — overrides
     self-attention K/V entirely.
     Returns (out (B, T, d), new_cache | None).
@@ -501,9 +521,9 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions,
             # Prompts still enter the pool via slot adoption
             # (models.adopt_slot); this path extends live sequences only.
             new_cache = paged_append_kv(cache, k, v, cache_pos,
-                                        block_table, cfg.quant.kv_fmt)
-            bs = cache.k_codes.shape[2]
-            S = block_table.shape[1] * bs
+                                        block_table, cfg.quant.kv_fmt,
+                                        layer=layer)
+            S = block_table.shape[1] * cache.k_codes.shape[-2]
             k_pos = jnp.broadcast_to(
                 jnp.arange(S, dtype=jnp.int32)[None], (B, S))
             valid = k_pos <= positions[:, -1:]
@@ -512,11 +532,12 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions,
                           window=cfg.window, is_global=is_global)
             if T == 1:
                 packed_out = _sdpa_paged_cache(q, new_cache, block_table,
-                                               bias3, lengths, cfg.quant)
+                                               bias3, lengths, cfg.quant,
+                                               layer)
             else:
                 packed_out = _sdpa_paged_verify(q, new_cache, block_table,
                                                 bias3, positions, lengths,
-                                                cfg.quant)
+                                                cfg.quant, layer)
         elif isinstance(cache, QuantizedKVCache):
             # packed cache: re-quantize ONLY the new entries (per-entry
             # scales — old codes are bit-frozen, see quant.kvcache)

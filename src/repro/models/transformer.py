@@ -219,12 +219,13 @@ def param_dims(cfg: ModelConfig) -> Dict:
 
 def _dense_body(pl, x, positions, cfg: ModelConfig, is_global,
                 cache: Optional[KVCache], cache_pos, cross_kv, cross_p,
-                block_table=None, lengths=None):
+                block_table=None, lengths=None, layer=None):
     """One dense/moe layer. Returns (x, new_kv, aux)."""
     h, new_kv = attention_apply(
         pl["attn"], rms_norm(x, pl["ln1"], cfg.norm_eps), cfg,
         positions=positions, is_global=is_global, cache=cache,
-        cache_pos=cache_pos, block_table=block_table, lengths=lengths)
+        cache_pos=cache_pos, block_table=block_table, lengths=lengths,
+        layer=layer)
     x = constrain(x + h, ("batch", "seq", "embed_act"))
     if cross_p is not None:
         hc, _ = attention_apply(
@@ -932,6 +933,33 @@ def _paged_kv_entries(kv: PagedKVCache) -> Dict[str, Any]:
             "k_scale": kv.k_scale, "v_scale": kv.v_scale}
 
 
+def _paged_layers(layers, flags, cfg: ModelConfig, x, positions, cache_pos,
+                  lengths, cache):
+    """Run ``layers`` over the paged pool; returns ``(x, cache)``.
+
+    The stacked ``(La, P, KV, bs, hd)`` pool planes ride in the scan
+    *carry*, never as ``xs``/``ys``: layer ``l`` appends its rows at
+    ``[l, phys, :, off]`` (one in-place scatter) and its kernel reads
+    layer ``l`` by tile offset (:func:`attention._paged_operands`), so
+    no per-layer slice and no restacked copy of the pool is made.
+    ``layers``/``flags`` may be a prefix of the stack (the draft step);
+    the pool's other layers pass through untouched.
+    """
+    bt = cache["block_table"]
+
+    def body(carry, xs):
+        x, kv = carry
+        pl, isg, layer = xs
+        x, kv, _ = _dense_body(pl, x, positions, cfg, isg, kv, cache_pos,
+                               None, None, block_table=bt,
+                               lengths=lengths, layer=layer)
+        return (x, kv), None
+    ids = jnp.arange(flags.shape[0], dtype=jnp.int32)
+    (x, kv), _ = jax.lax.scan(body, (x, _paged_kv_stack(cache)),
+                              (layers, flags, ids))
+    return x, dict(cache, **_paged_kv_entries(kv))
+
+
 def adopt_slot(cache, prefill_cache, slot, phys):
     """Copy a batch-1 dense prefill cache into pool blocks; activate slot.
 
@@ -999,27 +1027,13 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, cache):
     """
     _require_paged_arch(cfg)
     params = _cast_params(params, cfg)
-    B = tokens.shape[0]
     pos = cache["pos"]
-    bt = cache["block_table"]
     live = pos > 0
     lengths = jnp.where(live, pos + 1, 0)
     x = _embed_tokens(params, cfg, tokens)
     x = constrain(x, ("batch", "seq", "embed_act"))
-    positions = pos[:, None]
-
-    flags = _global_flags(cfg)
-
-    def body(x, xs):
-        pl, isg, kvl = xs
-        x, akv, _ = _dense_body(pl, x, positions, cfg, isg, kvl, pos,
-                                None, None, block_table=bt,
-                                lengths=lengths)
-        return x, akv
-    x, kvs = jax.lax.scan(
-        body, x, (params["layers"], flags, _paged_kv_stack(cache)))
-
-    new_cache = dict(cache, **_paged_kv_entries(kvs))
+    x, new_cache = _paged_layers(params["layers"], _global_flags(cfg), cfg,
+                                 x, pos[:, None], pos, lengths, cache)
     new_cache["pos"] = jnp.where(live, pos + 1, pos)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _logits(params, cfg, x)[:, 0], new_cache
@@ -1051,27 +1065,14 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, cache):
     """
     _require_paged_arch(cfg)
     params = _cast_params(params, cfg)
-    B, T = tokens.shape
+    T = tokens.shape[1]
     pos = cache["pos"]
-    bt = cache["block_table"]
-    live = pos > 0
-    lengths = jnp.where(live, pos + 1, 0)
+    lengths = jnp.where(pos > 0, pos + 1, 0)
     x = _embed_tokens(params, cfg, tokens)
     x = constrain(x, ("batch", "seq", "embed_act"))
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-
-    flags = _global_flags(cfg)
-
-    def body(x, xs):
-        pl, isg, kvl = xs
-        x, akv, _ = _dense_body(pl, x, positions, cfg, isg, kvl, pos,
-                                None, None, block_table=bt,
-                                lengths=lengths)
-        return x, akv
-    x, kvs = jax.lax.scan(
-        body, x, (params["layers"], flags, _paged_kv_stack(cache)))
-
-    new_cache = dict(cache, **_paged_kv_entries(kvs))
+    x, new_cache = _paged_layers(params["layers"], _global_flags(cfg), cfg,
+                                 x, positions, pos, lengths, cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _logits(params, cfg, x), new_cache
 
@@ -1081,10 +1082,12 @@ def draft_step_paged(params, cfg: ModelConfig, tokens, cache, offset):
 
     Runs only the first ``cfg.quant.draft_layers`` transformer layers
     (plus final norm and logits head) over sliced stacked params — the
-    truncated-layer self-draft. The draft's lower-layer K/V appends land
-    in the shared pool at ``pos + offset`` but are **overwritten by the
-    verify append before any verify read**, so draft numerics can only
-    change the acceptance *rate*, never an accepted token's bits.
+    truncated-layer self-draft — scanning them over the full carried
+    pool, whose upper layers pass through untouched. The draft's
+    lower-layer K/V appends land in the shared pool at ``pos + offset``
+    but are **overwritten by the verify append before any verify read**,
+    so draft numerics can only change the acceptance *rate*, never an
+    accepted token's bits.
     ``offset`` is traced: one compilation serves every draft position of
     a round. ``pos`` is not advanced.
 
@@ -1095,31 +1098,15 @@ def draft_step_paged(params, cfg: ModelConfig, tokens, cache, offset):
     L = min(L, cfg.n_layers)
     params = _cast_params(params, cfg)
     pos = cache["pos"]
-    bt = cache["block_table"]
     live = pos > 0
     offset = jnp.asarray(offset, jnp.int32)
     dpos = jnp.where(live, pos + offset, pos)
     lengths = jnp.where(live, dpos + 1, 0)
     x = _embed_tokens(params, cfg, tokens)
     x = constrain(x, ("batch", "seq", "embed_act"))
-    positions = dpos[:, None]
-
-    flags = _global_flags(cfg)[:L]
     lp = jax.tree.map(lambda a: a[:L], params["layers"])
-    kv_full = _paged_kv_stack(cache)
-    kv_draft = PagedKVCache(*(p[:L] for p in kv_full))
-
-    def body(x, xs):
-        pl, isg, kvl = xs
-        x, akv, _ = _dense_body(pl, x, positions, cfg, isg, kvl, dpos,
-                                None, None, block_table=bt,
-                                lengths=lengths)
-        return x, akv
-    x, kvs = jax.lax.scan(body, x, (lp, flags, kv_draft))
-
-    merged = PagedKVCache(*(jnp.concatenate([u, f[L:]], axis=0)
-                            for u, f in zip(kvs, kv_full)))
-    new_cache = dict(cache, **_paged_kv_entries(merged))
+    x, new_cache = _paged_layers(lp, _global_flags(cfg)[:L], cfg, x,
+                                 dpos[:, None], dpos, lengths, cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _logits(params, cfg, x)[:, 0], new_cache
 

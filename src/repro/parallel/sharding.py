@@ -28,7 +28,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["Rules", "TRAIN_RULES", "make_rules", "train_rules", "use_rules",
            "constrain", "resolve_spec", "current_rules", "named_sharding",
-           "prepared_plane_dims", "prepared_specs", "replicated_kernel_call"]
+           "prepared_plane_dims", "prepared_specs", "replicated_kernel_call",
+           "on_multi_device_mesh"]
 
 
 class Rules:
@@ -214,6 +215,14 @@ def use_rules(rules: Optional[Rules]):
         _ctx.rules = prev
 
 
+def on_multi_device_mesh() -> bool:
+    """Whether an active rules context spans more than one device —
+    where :func:`replicated_kernel_call` gathers its operands, and a
+    sharded array keeps its sharding only through shape-aligned ops."""
+    rules = current_rules()
+    return rules is not None and rules.mesh.size > 1
+
+
 def replicated_kernel_call(fn, *args):
     """Run one Pallas kernel call whole on every device of the active mesh.
 
@@ -227,11 +236,10 @@ def replicated_kernel_call(fn, *args):
     hold arrays, ``None`` and Python scalars; ``fn`` closes over static
     values only.
     """
-    rules = current_rules()
-    if rules is None or rules.mesh.size == 1:
+    if not on_multi_device_mesh():
         return fn(*args)
-    return jax.shard_map(fn, mesh=rules.mesh, in_specs=P(), out_specs=P(),
-                         check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=current_rules().mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def constrain(x, dims: Tuple[Optional[str], ...]):

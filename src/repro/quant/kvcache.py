@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.core.formats import (E4M3, FPFormat, decode_bits, encode_bits,
                                 round_to_format)
+from repro.parallel.sharding import on_multi_device_mesh
 
 __all__ = ["QuantizedKVCache", "quantize_kv", "append_kv",
            "init_quantized_kv", "dequantize_kv", "kv_cache_bytes",
@@ -258,7 +259,7 @@ def init_paged_kv(lead, n_blocks: int, n_heads: int, block_size: int,
 
 
 def paged_append_kv(cache: PagedKVCache, k_new, v_new, pos, block_table,
-                    fmt: FPFormat = E4M3) -> PagedKVCache:
+                    fmt: FPFormat = E4M3, layer=None) -> PagedKVCache:
     """Write each slot's ``T`` new K/V entries through its block table.
 
     The paged twin of :func:`append_kv`: quantize the ``B * T`` fresh
@@ -276,13 +277,16 @@ def paged_append_kv(cache: PagedKVCache, k_new, v_new, pos, block_table,
     a cheap draft pass left behind without any bit drift.
 
     Args:
-      cache: per-layer ``(P, KV, bs, hd)`` pool view.
+      cache: per-layer ``(P, KV, bs, hd)`` pool view, or the stacked
+        ``(La, P, KV, bs, hd)`` pool when ``layer`` is given.
       k_new / v_new: ``(B, T, KV, hd)`` fresh projections.
       pos: ``(B,)`` int32 logical write positions of token 0 (a free
         slot's ``pos = 0`` lands in its zeroed table's
         :data:`TRASH_BLOCK`).
       block_table: ``(B, nb)`` int32 physical block ids.
       fmt: the cache's code format.
+      layer: traced layer index into a stacked pool: the rows land at
+        ``[layer, phys, :, off]``.
 
     Returns:
       The pool with ``T`` entries per slot replaced.
@@ -301,16 +305,32 @@ def paged_append_kv(cache: PagedKVCache, k_new, v_new, pos, block_table,
     blk = jnp.clip(pos_t // bs, 0, nb - 1)
     phys = jnp.take_along_axis(block_table.astype(jnp.int32), blk, axis=1)
     off = pos_t % bs
-    phys_f, off_f = phys.reshape(-1), off.reshape(-1)
-    return PagedKVCache(
-        k_codes=cache.k_codes.at[phys_f, :, off_f, :].set(
-            kc.reshape(B * T, KV, hd)),
-        v_codes=cache.v_codes.at[phys_f, :, off_f, :].set(
-            vc.reshape(B * T, KV, hd)),
-        k_scale=cache.k_scale.at[phys_f, :, off_f].set(
-            ks.reshape(B * T, KV)),
-        v_scale=cache.v_scale.at[phys_f, :, off_f].set(
-            vs.reshape(B * T, KV)))
+    if on_multi_device_mesh():
+        # the pool is sharded over its head axis: keep that axis whole
+        # in the update window, so each device scatters its own heads
+        row = (phys.reshape(-1), slice(None), off.reshape(-1))
+        if layer is not None:
+            row = (layer,) + row
+
+        def put(plane, new):
+            return plane.at[row].set(new.reshape((B * T,) + new.shape[2:]))
+    else:
+        # one scatter of B*T*KV (tile, offset) rows into the pool's
+        # (..., bs, hd) tile view: a pure reshape whose scatter dims
+        # lead in order, so XLA updates the donated or loop-carried pool
+        # in place, with no transposed copy of it
+        if layer is not None:
+            phys = phys + layer * cache.k_codes.shape[-4]
+        tile = phys[:, :, None] * KV + jnp.arange(KV, dtype=jnp.int32)
+        row = (tile.reshape(-1), jnp.repeat(off.reshape(-1), KV))
+
+        def put(plane, new):
+            tail = new.shape[3:]
+            flat = plane.reshape((-1, bs) + tail)
+            return flat.at[row].set(new.reshape((-1,) + tail)).reshape(
+                plane.shape)
+    return PagedKVCache(put(cache.k_codes, kc), put(cache.v_codes, vc),
+                        put(cache.k_scale, ks), put(cache.v_scale, vs))
 
 
 def paged_rollback_kv(cache: PagedKVCache, block_table, start, count,

@@ -19,13 +19,21 @@ cases) pinning the contracts the continuous-batching engine leans on:
   block-boundary lengths, and both match the dense kernel over the
   gathered cache;
 * the masked-chunk early-exit (``lengths=``) on the dense entry point is
-  bitwise-identical to walking the zero-inert tail in full.
+  bitwise-identical to walking the zero-inert tail in full;
+* the paged steps carry the stacked pool through their layer scan: the
+  compiled program moves no pool-sized buffer, and every logit and pool
+  byte equals the per-layer ``xs``/``ys`` form, round after round.
 """
 
+import dataclasses
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import reduced_config
 from repro.core.formats import E4M3
 from repro.kernels.mgs_attention import (mgs_flash_attention,
                                          mgs_flash_attention_ref,
@@ -36,6 +44,14 @@ from repro.quant.kvcache import (BlockAllocator, PagedKVCache,
                                  init_paged_kv, init_quantized_kv,
                                  paged_append_kv, paged_rollback_kv,
                                  quantize_kv)
+from repro.models import init_params
+from repro.models.transformer import (_cast_params, _dense_body,
+                                      _embed_tokens, _global_flags,
+                                      _logits, decode_step_paged,
+                                      draft_step_paged, init_paged_cache,
+                                      rewind_slots, verify_step_paged)
+from repro.models.common import rms_norm
+from repro.quant import QuantConfig
 from repro.quant.quantize import quantize_fp8
 
 
@@ -502,3 +518,154 @@ def test_paged_verify_bitwise_per_token(rng, base_lengths):
             np.testing.assert_array_equal(
                 np.asarray(got[:, t]), np.asarray(solo),
                 err_msg=f"kernel={use_kernel} token {t}")
+
+
+def test_paged_kernel_reads_a_layer_of_the_stacked_pool_in_place(rng):
+    """The kernel reading layer ``l`` of a stacked pool through tile ids
+    offset by ``l * P`` gives the bits of the call on that layer's slice,
+    on both tiers — whatever the other layers hold."""
+    qv, kp, vp, bt, live, qk, vs, bias, _ = _paged_case(rng, (7, 0, 16))
+    P = kp.shape[0]
+
+    def stacked(pool):   # layer 1 of three; the others random bytes
+        junk = rng.integers(0, 255, (2,) + pool.shape).astype(np.uint8)
+        return jnp.concatenate([junk[0], pool, junk[1]])
+    ks, vs_ = stacked(kp), stacked(vp)
+    for use_kernel in (False, True):
+        alone = mgs_paged_flash_attention(qv, kp, vp, bt, live, qk, vs,
+                                          bias, E4M3, use_kernel=use_kernel)
+        in_place = mgs_paged_flash_attention(qv, ks, vs_, bt + P, live, qk,
+                                             vs, bias, E4M3,
+                                             use_kernel=use_kernel)
+        np.testing.assert_array_equal(np.asarray(in_place),
+                                      np.asarray(alone))
+
+
+# ---------------------------------------------------------------------------
+# paged steps: the stacked pool rides the layer scan's carry
+# ---------------------------------------------------------------------------
+
+_SPEC_K = 3
+_STEPS = {
+    "decode": decode_step_paged,
+    "verify": verify_step_paged,
+    "draft": functools.partial(draft_step_paged, offset=1),
+}
+
+
+def _step_cfg(draft_layers=1):
+    return dataclasses.replace(
+        reduced_config("deepseek-7b"),
+        quant=QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
+                          kv_cache="packed", per_row_act=True,
+                          block_m=32, block_n=32, block_k=32,
+                          draft_layers=draft_layers))
+
+
+@pytest.mark.parametrize("kind", sorted(_STEPS))
+def test_paged_step_moves_no_pool_buffer(kind, pool_moves):
+    """Compiled with the cache donated, as the engine runs it, a paged
+    step holds no copy, fill, slice, update-slice or concatenation of a
+    pool-sized buffer: each layer's append is one in-place scatter and
+    its attention reads the carried pool where it lies."""
+    cfg = _step_cfg()
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))[0])
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, 4, 64, 37)[0])
+    t = _SPEC_K if kind == "verify" else 1
+    tokens = jax.ShapeDtypeStruct((4, t), jnp.int32)
+    step = jax.jit(lambda p, tok, c: _STEPS[kind](p, cfg, tok, c),
+                   donate_argnums=(2,))
+    text = step.lower(params, tokens, cache).compile().as_text()
+    assert "scatter(" in text
+    assert pool_moves(text, cache["k"].shape) == []
+
+
+def _xs_ys_step(kind, params, cfg, tokens, cache, offset=0):
+    """Oracle: the paged steps with the pool planes as scan ``xs``/``ys``
+    — each layer's planes sliced out, appended and attended as a
+    one-layer pool, restacked, and the draft's upper layers concatenated
+    back."""
+    params = _cast_params(params, cfg)
+    pos = cache["pos"]
+    live = pos > 0
+    L = cfg.quant.draft_layers if kind == "draft" else cfg.n_layers
+    qpos = jnp.where(live, pos + offset, pos)
+    lengths = jnp.where(live, qpos + 1, 0)
+    positions = qpos[:, None] + jnp.arange(tokens.shape[1])[None]
+    planes = ("k", "v", "k_scale", "v_scale")
+
+    def body(x, xs):
+        pl, isg, *kvl = xs
+        x, kv, _ = _dense_body(pl, x, positions, cfg, isg,
+                               PagedKVCache(*(a[None] for a in kvl)), qpos,
+                               None, None, block_table=cache["block_table"],
+                               lengths=lengths, layer=0)
+        return x, tuple(a[0] for a in kv)
+    x, kvs = jax.lax.scan(
+        body, _embed_tokens(params, cfg, tokens),
+        (jax.tree.map(lambda a: a[:L], params["layers"]),
+         _global_flags(cfg)[:L], *(cache[p][:L] for p in planes)))
+    new = dict(cache, **{p: jnp.concatenate([u, cache[p][L:]])
+                         for p, u in zip(planes, kvs)})
+    if kind == "decode":
+        new["pos"] = jnp.where(live, pos + 1, pos)
+    logits = _logits(params, cfg, rms_norm(x, params["final_norm"],
+                                           cfg.norm_eps))
+    return (logits if kind == "verify" else logits[:, 0]), new
+
+
+def _ragged_pool(cfg, rng):
+    """Five slots over a pool whose every block holds quantized data:
+    slots 0 and 3 free, slot 1 at the last offset of its first block,
+    slot 2 at the first offset of its second, slot 4 mid-block."""
+    cache, _ = init_paged_cache(cfg, 5, 96, 16)
+    for p, s in (("k", "k_scale"), ("v", "v_scale")):
+        codes, scale = quantize_kv(jnp.asarray(rng.normal(
+            0, 1, cache[p].shape).astype(np.float32)), E4M3)
+        cache[p], cache[s] = codes, scale
+    cache["block_table"] = jnp.asarray(
+        [[0, 0, 0], [3, 7, 9], [1, 4, 11], [0, 0, 0], [2, 5, 14]], jnp.int32)
+    cache["pos"] = jnp.asarray([0, 31, 32, 0, 5], jnp.int32)
+    return cache
+
+
+def _assert_same(got, want, what):
+    for g, w, name in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                          jax.tree_util.tree_flatten_with_path(got)[0]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"{what} {name[0]}")
+
+
+@pytest.mark.parametrize("kind", sorted(_STEPS))
+def test_paged_step_bitwise_vs_xs_ys_form(kind, rng):
+    """Three rounds of each step kind (each speculative round closed by
+    a rewind) over a ragged pool with free slots and block-boundary
+    positions: logits and every pool byte, codes and scales, equal the
+    per-layer ``xs``/``ys`` form's after every round. The draft runs
+    two of the four layers, so it too addresses a layer past the first."""
+    cfg = _step_cfg(draft_layers=2)
+    params, _ = init_params(cfg, jax.random.PRNGKey(3))
+    new = old = _ragged_pool(cfg, rng)
+    B = new["pos"].shape[0]
+    steps = {"decode": [0], "verify": [0], "draft": [0, 1]}[kind]
+    t = _SPEC_K if kind == "verify" else 1
+    for rnd in range(3):
+        for offset in steps:
+            tokens = jnp.asarray(rng.integers(1, cfg.vocab, (B, t)),
+                                 jnp.int32)
+            if kind == "draft":
+                got = jax.jit(draft_step_paged, static_argnums=1)(
+                    params, cfg, tokens, new, offset)
+            else:
+                got = jax.jit(_STEPS[kind], static_argnums=1)(
+                    params, cfg, tokens, new)
+            want = jax.jit(_xs_ys_step, static_argnums=(0, 2))(
+                kind, params, cfg, tokens, old, offset)
+            _assert_same(got, want, f"{kind} round {rnd} offset {offset}")
+            new, old = got[1], want[1]
+        if kind != "decode":
+            keep = jnp.asarray(rng.integers(1, _SPEC_K + 1, B), jnp.int32)
+            new = rewind_slots(new, keep, _SPEC_K)
+            old = rewind_slots(old, keep, _SPEC_K)
+            _assert_same(new, old, f"{kind} round {rnd} rewind")

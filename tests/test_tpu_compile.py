@@ -1,12 +1,14 @@
 """The main path's Pallas kernels compile for a TPU v5e chip.
 
-Each test compiles one kernel at deepseek-7b widths for a chip that is
-described (``jax.experimental.topologies``) and not attached, with
-``interpret=False`` passed explicitly: what Mosaic refuses here it would
+Each test compiles one kernel, or the paged decode step around them, at
+deepseek-7b widths for a chip that is described
+(``jax.experimental.topologies``) and not attached, with the kernels
+out of interpret mode: what Mosaic or XLA:TPU refuses here it would
 refuse on the chip. Nothing runs, so these say nothing about results or
 times; the kernel-vs-reference tests cover results.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -14,11 +16,16 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.kernels import mgs_attention, ops
 from repro.kernels.mgs_attention import (mgs_paged_flash_attention,
                                          mgs_paged_verify_attention)
 from repro.kernels.mgs_matmul import (WS_STRIPE_BUDGET_BYTES,
                                       mgs_matmul_exact_fused_pallas,
                                       ws_stripe_bytes)
+from repro.models import init_params
+from repro.models.transformer import decode_step_paged, init_paged_cache
+from repro.quant.config import FP8_MGS_SERVE_PAGED
 
 D_MODEL, D_FF = 4096, 11008            # deepseek-7b
 KV_HEADS, HEAD_DIM, BLOCK = 32, 128, 128
@@ -101,3 +108,33 @@ def test_paged_flash_attention_compiles(one_chip):
 def test_paged_verify_attention_compiles(one_chip):
     _compile(lambda *a: mgs_paged_verify_attention(*a, interpret=False),
              *_paged_operands(one_chip, 4))
+
+
+def test_paged_decode_step_moves_no_pool_buffer(one_chip, monkeypatch,
+                                                pool_moves):
+    """The slot engine's decode step (2 layers at deepseek-7b widths, 32
+    slots, a 641-block pool, blocks of 128, fused kernels), compiled for
+    the chip with the cache donated, appends by in-place scatter and
+    copies, fills, slices, update-slices and concatenates no pool-sized
+    buffer. The weights are the raw tree (the step quantizes them); the
+    engine's prepared planes do not touch the pool."""
+    for mod in (mgs_attention, ops):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=2,
+                              quant=FP8_MGS_SERVE_PAGED)
+    assert cfg.quant.block_k == BLOCK
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))[0]))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_cache(cfg, 32, 2560, 641)[0]))
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t, c: decode_step_paged(p, cfg, t, c),
+                   donate_argnums=(2,))
+    text = step.lower(params, tokens, cache).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "scatter(" in text
+    assert pool_moves(text, cache["k"].shape) == []
