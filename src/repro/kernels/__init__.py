@@ -9,6 +9,10 @@ Public entry points (all jitted; tests run them in interpret mode on CPU):
   K-resident weight-stationary schedule ("weight") that caches decoded
   weight limbs in VMEM scratch across the M-grid axis (bit-identical,
   grid_m-fold less in-kernel weight decode work).
+* :func:`fused_tiles` — the output-stationary schedule's tiles, read off
+  the call's shape (real rows in M, long K and N tiles that divide the
+  weight); ``TILE_STATS`` records each traced call shape's tiles and
+  grid steps.
 * :func:`mgs_matmul_exact_pallas` — the pre-decomposed exact kernel:
   streams 3 int8 limb planes per operand (3 bytes/elem, the A/B
   baseline); accepts cached ``PreparedWeight`` limb planes via
@@ -31,14 +35,14 @@ from .mgs_attention import (flash_chunk_limit, mgs_flash_attention,
                             mgs_flash_attention_ref,
                             mgs_paged_flash_attention,
                             mgs_paged_verify_attention)
-from .mgs_matmul import (ACTIVATIONS, WS_STRIPE_BUDGET_BYTES, limb_decompose,
-                         mgs_matmul_dmac_pallas,
+from .mgs_matmul import (ACTIVATIONS, TILE_STATS, WS_STRIPE_BUDGET_BYTES,
+                         fused_tiles, limb_decompose, mgs_matmul_dmac_pallas,
                          mgs_matmul_exact_fused_pallas,
                          mgs_matmul_exact_pallas, worst_case_flush_period,
                          ws_stripe_bytes)
 
-__all__ = ["ops", "ref", "ACTIVATIONS", "WS_STRIPE_BUDGET_BYTES",
-           "limb_decompose", "mgs_matmul_dmac_pallas",
+__all__ = ["ops", "ref", "ACTIVATIONS", "TILE_STATS", "WS_STRIPE_BUDGET_BYTES",
+           "fused_tiles", "limb_decompose", "mgs_matmul_dmac_pallas",
            "mgs_matmul_exact_fused_pallas", "mgs_matmul_exact_pallas",
            "worst_case_flush_period", "ws_stripe_bytes",
            "mgs_flash_attention", "mgs_flash_attention_ref",

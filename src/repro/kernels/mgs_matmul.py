@@ -37,18 +37,28 @@ int32 so the in-VMEM totals are exact — the wide-fallback path never loses
 bits, so this is bit-identical to the hardware). The 16× shift+combine
 runs once per output tile.
 
-Memory accounting (per grid step, MXU-aligned 128×128×128 tiles):
+Memory accounting (per grid step):
 
 * HBM operand bytes per full (M, K) @ (K, N) matmul:
     fused:          M·K + K·N          (packed codes, 1 B/elem)
     pre-decomposed: 3·(M·K + K·N)      (3 int8 limb planes)
   plus 4·M·N output bytes either way — the fused path's operand traffic
   is exactly 1/3 of the pre-decomposed path's.
-* VMEM, fused: bm·bk + bk·bn uint8 codes + 3·(bm·bk + bk·bn) int8 decoded
-  limbs (transient) + 5·bm·bn int32 + bm·bn f32 + 2·bn f32 epilogue rows
-  ≈ 0.6 MB.
-* VMEM, pre-decomposed: 3·(bm·bk + bk·bn) int8 + 5·bm·bn int32 + bm·bn
-  f32 ≈ 0.5 MB.
+* VMEM, fused output-stationary, at the tiles :func:`fused_tiles` reads
+  off the call's shape (``ops.mgs_matmul`` passes them): bm = the call's
+  rows rounded up to 32 (at most 256 a tile), bk and bn the longest
+  128-multiples up to 512 that divide the 128-padded K and N. Per step,
+  :func:`fused_vmem_bytes`: double-buffered uint8 code tiles
+  2·(bm·bk + bk·bn), transient int8 limbs 3·(bm·bk + bk·bn) and int32
+  decode temporaries 8·(bm·bk + bk·bn), 5·bm·bn int32 class
+  accumulators, bm·bn f32 wide accumulator, a double-buffered bm·bn f32
+  output tile, two bm·bn int32 dot results and 4·bn f32 epilogue rows:
+  4.1 MB at a decode step's 32×512×512 (M×K×N), 9.9 MB at a prefill's
+  256×512×512, 1.0 MB at 128×128×128; at most
+  ``FUSED_VMEM_BUDGET_BYTES`` (12 MB, under the 16 MB default scoped
+  limit). The stationary schedules keep the caller's tiles.
+* VMEM, pre-decomposed (128×128×128 default tiles): 3·(bm·bk + bk·bn)
+  int8 + 5·bm·bn int32 + bm·bn f32 ≈ 0.5 MB.
 * dmac: bm·bk·bn f32 product tile dominates (32·128·32·4 = 0.5 MB).
 """
 
@@ -65,7 +75,9 @@ from repro.core.formats import E4M3, FPFormat
 
 __all__ = ["mgs_matmul_exact_pallas", "mgs_matmul_exact_fused_pallas",
            "mgs_matmul_dmac_pallas", "limb_decompose",
-           "worst_case_flush_period", "default_interpret", "ACTIVATIONS"]
+           "worst_case_flush_period", "default_interpret", "ACTIVATIONS",
+           "fused_tiles", "fused_vmem_bytes", "FUSED_VMEM_BUDGET_BYTES",
+           "TILE_STATS"]
 
 _LIMB_BASE = 7
 _N_LIMBS = 3
@@ -379,6 +391,106 @@ def _exact_fused_stationary_kernel(xc_ref, wc_ref, scale_ref, bias_ref,
                                activation, has_scale, has_bias)
 
 
+# ---------------------------------------------------------------------------
+# output-stationary tiles, chosen from the call's shape
+# ---------------------------------------------------------------------------
+
+_LANE = 128          # lane tile: K and N tiles are multiples of it
+_U8_SUBLANE = 32     # rows of a uint8 sublane tile: M tiles are multiples
+# Largest tiles the chooser takes (v5e): a decode step's 32 slot rows
+# are one M tile, a prefill bucket of 512 rows two.
+_BM_CAP, _BK_CAP, _BN_CAP = 256, 512, 512
+
+# VMEM an output-stationary grid step may take: under the chip's 16 MB
+# default scoped limit, with room for what ``fused_vmem_bytes`` leaves out.
+FUSED_VMEM_BUDGET_BYTES = 12 << 20
+
+#: trace-time record of each fused call shape: (M, K, N) ->
+#: (block_m, block_n, block_k, grid steps). Written when a shape is
+#: traced, so a compiled program's calls appear once each.
+TILE_STATS: dict = {}
+
+
+def fused_vmem_bytes(block_m: int, block_n: int, block_k: int) -> int:
+    """VMEM bytes of one output-stationary fused grid step.
+
+    Per operand element (``bm·bk + bk·bn``): the double-buffered uint8
+    code tiles (2 B), the three int8 limbs (3 B) and two live int32
+    decode temporaries (8 B). Per output element (``bm·bn``): the five
+    int32 class accumulators (20 B), the f32 wide accumulator (4 B), the
+    double-buffered f32 output tile (8 B) and two int32 dot results
+    (8 B); plus the double-buffered scale and bias rows. The one size
+    formula the chooser (:func:`fused_tiles`) and the kernel's hard
+    check share. It bounds what Mosaic allocates for these tiles: the
+    least scoped VMEM limit a v5e compile accepts at M x K x N tiles
+    32x512x512 / 256x512x512 / 256x256x512 is 3.44 / 9.17 / 6.98 MB,
+    this gives 4.09 / 9.88 / 7.45 MB.
+    """
+    operand = block_m * block_k + block_k * block_n
+    return (operand * (2 + _N_LIMBS + 2 * 4)
+            + block_m * block_n * (_N_CLASSES * 4 + 4 + 2 * 4 + 2 * 4)
+            + 2 * 2 * 4 * block_n)
+
+
+def _row_tile(m: int, cap: int) -> int:
+    """Fewest M tiles of at most ``cap`` rows, each rounded up to the
+    uint8 sublane tile: 32 slot rows are one tile of 32, 300 rows two
+    of 160."""
+    tiles = _ceil(max(m, 1), cap)
+    return _ceil(_ceil(max(m, 1), tiles), _U8_SUBLANE) * _U8_SUBLANE
+
+
+def _lane_tiles(d: int, cap: int):
+    """K or N tiles, longest first: each a multiple of 128 up to ``cap``
+    that divides ``d`` rounded up to 128, so a weight whose dims are
+    multiples of 128 is never padded. A dimension under one lane tile is
+    its own (full-dimension) tile."""
+    if d < _LANE:
+        return [d]
+    units = _ceil(d, _LANE)
+    return [t * _LANE for t in range(min(cap // _LANE, units), 0, -1)
+            if units % t == 0]
+
+
+def fused_tiles(M: int, K: int, N: int, *,
+                flush_block_k: int | None = None):
+    """Tiles ``(block_m, block_n, block_k)`` of the output-stationary
+    fused kernel for an ``(M, K) @ (K, N)`` call, read off its shape.
+
+    ``block_m`` holds the real rows (rounded up to 32, at most 256 per
+    tile); ``block_k`` and ``block_n`` are the longest 128-multiples up
+    to 512 that divide the 128-padded K and N (deepseek-7b: 512 for
+    4096 and 102400, 256 for 11008 = 128·86). The integer limb sums are
+    exact under any partition, so the result keeps every bit of the
+    128x128x128 tiling provided the flushes fall at the same K
+    positions:
+
+    * ``flush_block_k=None`` — the period is the static worst-case bound.
+      ``block_k`` is taken only where K then still flushes once, at its
+      end (``K / block_k <= worst_case_flush_period(block_k)``).
+    * ``flush_block_k=b`` — a runtime period counts K-steps of ``b``
+      (the caller's ``block_k``, as the planner planned it), so
+      ``block_k = b``.
+
+    Only a long caller ``block_k`` can overflow the VMEM budget
+    (:func:`fused_vmem_bytes` of the capped tiles is 9.9 MB); then
+    ``block_n``, and after it ``block_m``, shrink until it fits.
+    """
+    if flush_block_k is not None:
+        bk = flush_block_k
+    else:
+        bk = next((t for t in _lane_tiles(K, _BK_CAP)
+                   if _ceil(K, t) <= worst_case_flush_period(t)), _LANE)
+    bm = _row_tile(M, _BM_CAP)
+    bns = _lane_tiles(N, _BN_CAP)
+    bn = next((t for t in bns if fused_vmem_bytes(bm, t, bk)
+               <= FUSED_VMEM_BUDGET_BYTES), bns[-1])
+    while (fused_vmem_bytes(bm, bn, bk) > FUSED_VMEM_BUDGET_BYTES
+           and bm > _U8_SUBLANE):
+        bm = _ceil(bm // 2, _U8_SUBLANE) * _U8_SUBLANE
+    return bm, bn, bk
+
+
 # VMEM budget for a stationary schedule's K-resident decoded limb stripe
 # (3 int8 planes x Kp x block_n for "weight", x block_m for
 # "activation"). Above this the schedule cannot co-reside with the
@@ -424,8 +536,10 @@ def mgs_matmul_exact_fused_pallas(x_codes, w_codes, fmt: FPFormat = E4M3, *,
         per-channel quantization scales), fused into the epilogue.
       bias: optional (N,) bias row, fused into the epilogue.
       activation: one of ``ACTIVATIONS``, fused into the epilogue.
-      block_m / block_n / block_k: Pallas tile sizes (MXU-aligned
-        defaults).
+      block_m / block_n / block_k: Pallas tile sizes. For the
+        output-stationary schedule ``ops.mgs_matmul`` passes
+        :func:`fused_tiles` of the call's shape; such tiles must fit
+        ``FUSED_VMEM_BUDGET_BYTES`` (:func:`fused_vmem_bytes`).
       flush_period: K-grid steps between narrow->wide accumulator
         flushes; ``None`` = deterministic
         :func:`worst_case_flush_period`, or a Markov-planned period from
@@ -482,6 +596,8 @@ def mgs_matmul_exact_fused_pallas(x_codes, w_codes, fmt: FPFormat = E4M3, *,
     nsteps = Kp // block_k
     fp = _flush_scalar(flush_period, block_k, nsteps)
     out_scale = 2.0 ** (-2 * (fmt.bias + fmt.mbits))
+    TILE_STATS[(M, K, N)] = (block_m, block_n, block_k,
+                             (Mp // block_m) * (Np // block_n) * nsteps)
 
     kw = dict(nsteps=nsteps, out_scale=out_scale,
               fmt=fmt, activation=activation, has_scale=has_scale,
@@ -540,6 +656,13 @@ def mgs_matmul_exact_fused_pallas(x_codes, w_codes, fmt: FPFormat = E4M3, *,
             interpret=interpret,
         )(xc, wc, srow, brow, fp)
         return out[:M, :N]
+    vmem = fused_vmem_bytes(block_m, block_n, block_k)
+    if vmem > FUSED_VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"output-stationary tiles {block_m}x{block_k}x{block_n} "
+            f"(MxKxN) need {vmem / 2**20:.1f} MB of VMEM > "
+            f"{FUSED_VMEM_BUDGET_BYTES / 2**20:.0f} MB budget; take "
+            f"fused_tiles(M, K, N) or smaller tiles")
     out = pl.pallas_call(
         functools.partial(_exact_fused_kernel, **kw),
         grid=(Mp // block_m, Np // block_n, nsteps),

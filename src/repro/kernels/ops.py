@@ -2,7 +2,8 @@
 
 ``mgs_matmul`` dispatches to the Pallas kernels (TPU; tests run them in
 interpret mode on CPU) or to the pure-jnp reference, honoring the
-QuantConfig block shapes. Batched LHS (..., K) is flattened to (M, K).
+QuantConfig block shapes except where the fused kernel picks its own.
+Batched LHS (..., K) is flattened to (M, K).
 
 Exact mode has two kernel variants selected by ``fused``:
 
@@ -11,7 +12,11 @@ Exact mode has two kernel variants selected by ``fused``:
   (``quant.prepared.PreparedWeight``).
 * ``fused=True``: operands streamed as packed FP8 codes (1 byte/elem),
   decoded + limb-split per tile in VMEM, with the dequant-scale / bias /
-  activation epilogue fused into the kernel's final grid step.
+  activation epilogue fused into the kernel's final grid step. The
+  output-stationary schedule takes its tiles from the call's shape
+  (``mgs_matmul.fused_tiles``), not from ``block_m/n``; ``block_k``
+  stays its K tile only under a runtime flush period, whose steps it
+  counts.
 
 ``scale``, ``bias`` and ``activation`` form the exact-mode epilogue
 ``activation(out * scale + bias)``; on the non-fused paths it is applied
@@ -30,7 +35,7 @@ from repro.core.formats import E4M3, FPFormat, encode_bits
 from repro.parallel.sharding import replicated_kernel_call
 from . import mgs_matmul as _mm
 from . import ref as _ref
-from .mgs_matmul import (ACTIVATIONS, default_interpret,
+from .mgs_matmul import (ACTIVATIONS, default_interpret, fused_tiles,
                          mgs_matmul_dmac_pallas,
                          mgs_matmul_exact_fused_pallas,
                          mgs_matmul_exact_pallas, ws_stripe_bytes)
@@ -165,6 +170,12 @@ def mgs_matmul(x, w, fmt: FPFormat = E4M3, mode: str = "exact", *,
         xc = x2 if x2.dtype == jnp.uint8 else encode_bits(x2, fmt)
         wc = w.codes if prepared else encode_bits(w, fmt)
         sched = _fused_schedule(schedule, K, block_m, block_n, block_k)
+        if sched == "output":
+            # tiles from the call's shape; a runtime flush period counts
+            # K-steps of the caller's block_k, which then stays the K tile
+            block_m, block_n, block_k = fused_tiles(
+                x2.shape[0], K, n_out,
+                flush_block_k=None if flush_period is None else block_k)
         out = replicated_kernel_call(
             lambda xc, wc, s, b, fp: mgs_matmul_exact_fused_pallas(
                 xc, wc, fmt, scale=s, bias=b, activation=activation,

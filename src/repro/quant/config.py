@@ -69,7 +69,16 @@ class QuantConfig:
         cutting activation decode work grid_n-fold (wide-N layers). All
         three are bit-identical; stationary schedules fall back to
         "output" with a warning when the stripe exceeds the VMEM budget.
-      block_m/n/k: Pallas tile sizes (MXU-aligned defaults).
+      block_m/n/k: Pallas tiles of the non-fused kernels (the
+        pre-decomposed exact kernel, dmac) and of the fused kernel's
+        stationary schedules. The fused output-stationary kernel reads
+        its tiles off each call's shape
+        (``kernels.mgs_matmul.fused_tiles``) and ignores block_m/n.
+        block_k is besides the paged KV cache's page (block) size, the
+        flash-attention chunk, and the unit of a runtime flush period
+        (``flush_target`` or a calibrated flush entry counts K-steps of
+        block_k): under such a period the fused kernel keeps block_k as
+        its K tile, so its flushes fall at the planned K positions.
       flush_target: probabilistic overflow budget used by the Markov
         planner (core.markov.plan_flush_period) to derive the kernel flush
         period; None = deterministic worst-case bound.

@@ -9,7 +9,9 @@ times; the kernel-vs-reference tests cover results.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,14 +22,16 @@ from repro.configs import get_config
 from repro.kernels import mgs_attention, ops
 from repro.kernels.mgs_attention import (mgs_paged_flash_attention,
                                          mgs_paged_verify_attention)
-from repro.kernels.mgs_matmul import (WS_STRIPE_BUDGET_BYTES,
+from repro.kernels.mgs_matmul import (WS_STRIPE_BUDGET_BYTES, fused_tiles,
                                       mgs_matmul_exact_fused_pallas,
                                       ws_stripe_bytes)
 from repro.models import init_params
 from repro.models.transformer import decode_step_paged, init_paged_cache
+from repro.quant import prepare_logits_head, prepare_params
+from repro.quant import prepared as prepared_mod
 from repro.quant.config import FP8_MGS_SERVE_PAGED
 
-D_MODEL, D_FF = 4096, 11008            # deepseek-7b
+D_MODEL, D_FF, VOCAB = 4096, 11008, 102400   # deepseek-7b
 KV_HEADS, HEAD_DIM, BLOCK = 32, 128, 128
 SLOTS, CONTEXT = 8, 2048
 
@@ -70,11 +74,31 @@ def _compile(fn, *shapes):
 @pytest.mark.parametrize("k,n", [(D_MODEL, D_MODEL), (D_MODEL, D_FF),
                                  (D_FF, D_MODEL)], ids=["wq", "wg", "wd"])
 def test_fused_matmul_compiles(one_chip, schedule, m, k, n):
+    """The output-stationary schedule at the tiles ``ops.mgs_matmul``
+    gives it (``fused_tiles``); the stationary ones at 128x128x128."""
+    tiles = {}
+    if schedule == "output":
+        tiles = dict(zip(("block_m", "block_n", "block_k"),
+                         fused_tiles(m, k, n)))
+
     def fn(x, w):
         return mgs_matmul_exact_fused_pallas(x, w, schedule=schedule,
-                                             interpret=False)
+                                             interpret=False, **tiles)
     _compile(fn, jax.ShapeDtypeStruct((m, k), jnp.uint8, sharding=one_chip),
              jax.ShapeDtypeStruct((k, n), jnp.uint8, sharding=one_chip))
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (32, D_MODEL, VOCAB), (1, D_MODEL, VOCAB),
+    (32, D_MODEL, D_MODEL), (32, D_MODEL, D_FF), (32, D_FF, D_MODEL),
+    (2048, D_MODEL, D_MODEL), (2048, D_MODEL, D_FF), (2048, D_FF, D_MODEL),
+], ids=["head-decode", "head-prefill", "wq-slots", "wg-slots", "wd-slots",
+        "wq-2048", "wg-2048", "wd-2048"])
+def test_fused_matmul_compiles_at_chosen_tiles(one_chip, m, k, n):
+    """The cell's decode calls (32 slot rows), the logits head at decode
+    and at batch-1 prefill, and the largest prefill bucket, each at the
+    tiles ``fused_tiles`` gives."""
+    test_fused_matmul_compiles(one_chip, "output", m, k, n)
 
 
 @pytest.mark.parametrize("schedule", ["weight", "activation"])
@@ -138,3 +162,45 @@ def test_paged_decode_step_moves_no_pool_buffer(one_chip, monkeypatch,
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "scatter(" in text
     assert pool_moves(text, cache["k"].shape) == []
+
+
+_U8_MOVE = re.compile(r"= u8\[([\d,]+)\](?:\{[^}]*\})? (pad|copy)\(")
+
+
+def test_paged_decode_step_pads_no_weight(one_chip, monkeypatch):
+    """The same decode step over the engine's prepared weights (packed
+    uint8 codes of every layer weight and of the tied logits head):
+    the fused matmul's K and N tiles divide every weight, so no code
+    plane is padded or copied into a larger one."""
+    for mod in (mgs_attention, ops):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=2,
+                              quant=FP8_MGS_SERVE_PAGED)
+
+    # shapes only: the build reads each weight's limb sigma back as a
+    # python float, which a shape-only trace has no value for
+    monkeypatch.setattr(prepared_mod, "float", lambda v: 1.0, raising=False)
+
+    def prepared():
+        p = init_params(cfg, jax.random.PRNGKey(0))[0]
+        p = prepare_params(p, cfg.quant)
+        return prepare_logits_head(p, cfg.quant, tied=cfg.tie_embeddings)
+    params = jax.eval_shape(prepared)
+    codes = [a.size for a in jax.tree.leaves(params) if a.dtype == jnp.uint8]
+    assert min(codes) >= D_MODEL * D_MODEL
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_cache(cfg, 32, 2560, 641)[0]))
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t, c: decode_step_paged(p, cfg, t, c),
+                   donate_argnums=(2,))
+    text = step.lower(on_chip(params), tokens, cache).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    moved = [line.strip() for line in text.splitlines()
+             if (m := _U8_MOVE.search(line))
+             and math.prod(int(d) for d in m.group(1).split(","))
+             >= D_MODEL * D_MODEL]
+    assert moved == []
