@@ -18,7 +18,7 @@ def main() -> None:
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
     from . import (decode_bench, drift, failover, fig3_dot_error,
-                   fig4_overflow, fig5_markov, fig9_pareto, kernel_bench,
+                   fig4_overflow, fig5_markov, fig9_pareto,
                    replica_throughput, roofline_table, spec_bench,
                    table1_accuracy, table3_energy)
     suites = {
@@ -28,7 +28,6 @@ def main() -> None:
         "fig9": fig9_pareto.run,
         "table1": table1_accuracy.run,
         "table3": table3_energy.run,
-        "kernel": kernel_bench.run,
         "roofline": roofline_table.run,
         "replica": replica_throughput.run,
         "decode": decode_bench.run,

@@ -107,8 +107,8 @@ def run(csv):
     from repro.models import init_params
     from repro.quant.config import FP8_MGS_SERVE_PAGED
 
-    q = FP8_MGS_SERVE_PAGED.replace(use_kernel=False, fused=False,
-                                    block_m=32, block_n=32, block_k=32)
+    q = FP8_MGS_SERVE_PAGED.replace(use_kernel=False, block_m=32,
+                                    block_n=32, block_k=32)
     base_cfg = dataclasses.replace(reduced_config("deepseek-7b"), quant=q)
     params, dims = init_params(base_cfg, jax.random.PRNGKey(0))
     mesh = make_mesh((1, 1), ("data", "model"))

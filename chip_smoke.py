@@ -92,7 +92,7 @@ def smoke_config(depth: int = DEPTH):
     cfg = dataclasses.replace(cfg, n_layers=depth, quant=FP8_MGS_SERVE_PAGED)
     print(f"config {ARCH}: depth {depth} of {get_config(ARCH).n_layers} "
           f"layers, quant FP8_MGS_SERVE_PAGED (use_kernel="
-          f"{cfg.quant.use_kernel}, fused={cfg.quant.fused})", flush=True)
+          f"{cfg.quant.use_kernel})", flush=True)
     return cfg
 
 

@@ -76,8 +76,7 @@ def main():
     ref = X @ W
     for q in (QuantConfig(dtype="fp8_e4m3", accum="wide"),
               QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                          use_kernel=True, block_m=32, block_n=32,
-                          block_k=64),
+                          use_kernel=True),
               QuantConfig(dtype="fp8_e4m3", accum="mgs_dmac")):
         out = np.asarray(qmatmul(jnp.asarray(X), jnp.asarray(W), q))
         rel = np.abs(out - ref).max() / np.abs(ref).max()

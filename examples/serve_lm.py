@@ -15,18 +15,17 @@ docs/replica_serving.md).
 
 Serving with prepared weights
 -----------------------------
-Static weights are quantized + limb-decomposed exactly once per process:
+Static weights are quantized + encoded exactly once per process:
 ``ServeEngine`` calls ``quant.prepare_params`` at construction, replacing
 every proj-consumed weight with a ``PreparedWeight`` holding
 
-* packed FP8 codes (uint8, 1 byte/elem) — streamed by the fused kernel,
-* int8 limb planes — the pre-decomposed A/B kernel's input,
+* packed FP8 codes (uint8, 1 byte/elem) — streamed by the exact kernel,
 * the cached dequant scale and observed limb statistics (which feed the
   Markov flush planner via ``QuantConfig.flush_target``).
 
 No request ever re-quantizes a parameter; ``quant.PREP_STATS`` proves it
 (printed below). On TPU the production config is
-``quant.config.FP8_MGS_SERVE`` (fused exact kernel + in-kernel epilogue);
+``quant.config.FP8_MGS_SERVE`` (exact kernel + in-kernel epilogue);
 on CPU this example uses the jnp emulation path, which also consumes the
 prepared planes.
 """
@@ -106,10 +105,9 @@ def main():
                              "engine — bit-identity regression")
 
     print("\nNote: wall-clock on CPU reflects the *emulation*; on TPU the "
-          "fused limb kernel (quant.config.FP8_MGS_SERVE) streams packed "
-          "FP8 codes (1/3 the operand HBM bytes of pre-decomposed limbs, "
-          "see benchmarks/kernel_bench.py) and fuses the scale/activation "
-          "epilogue into the matmul.")
+          "limb kernel (quant.config.FP8_MGS_SERVE) streams packed FP8 "
+          "codes, 1 byte per operand element, and fuses the "
+          "scale/activation epilogue into the matmul.")
 
 
 if __name__ == "__main__":

@@ -16,7 +16,7 @@ Structure (one ``(T, D)`` query slice attending ``(S, D)`` keys/values):
   ``o``) lives in VMEM scratch across the chunk axis, exactly like the
   matmul kernels' class accumulators live across the K grid axis.
 * the query's decoded limbs are cached in VMEM scratch on the first
-  chunk (the activation-stationary trick from ``mgs_matmul``): q is
+  chunk (an activation-stationary schedule over the chunk axis): q is
   decoded once, not once per chunk.
 * scores: exact integer contraction of q and k limbs over ``D``, single
   flush (``D`` fits one tile, far inside ``worst_case_flush_period``),

@@ -1,26 +1,21 @@
 """Jitted public wrappers around the MGS Pallas kernels.
 
 ``mgs_matmul`` dispatches to the Pallas kernels (TPU; tests run them in
-interpret mode on CPU) or to the pure-jnp reference, honoring the
-QuantConfig block shapes except where the fused kernel picks its own.
-Batched LHS (..., K) is flattened to (M, K).
+interpret mode on CPU) or to the pure-jnp reference. Batched LHS
+(..., K) is flattened to (M, K).
 
-Exact mode has two kernel variants selected by ``fused``:
-
-* ``fused=False`` (default): host-side limb decomposition, 3 int8 limb
-  planes per operand streamed from HBM. Weight planes may be precomputed
-  (``quant.prepared.PreparedWeight``).
-* ``fused=True``: operands streamed as packed FP8 codes (1 byte/elem),
-  decoded + limb-split per tile in VMEM, with the dequant-scale / bias /
-  activation epilogue fused into the kernel's final grid step. The
-  output-stationary schedule takes its tiles from the call's shape
-  (``mgs_matmul.fused_tiles``), not from ``block_m/n``; ``block_k``
-  stays its K tile only under a runtime flush period, whose steps it
-  counts.
+Exact mode has one kernel, ``mgs_matmul.mgs_matmul_exact_fused_pallas``:
+operands are streamed as packed FP8 codes (1 byte/elem), decoded +
+limb-split per tile in VMEM, with the dequant-scale / bias / activation
+epilogue fused into the kernel's final grid step. It takes its tiles
+from the call's shape (``mgs_matmul.fused_tiles``), not from
+``block_m/n``; ``block_k`` stays its K tile only under a runtime flush
+period, whose steps it counts. ``block_m/n/k`` are the dmac kernel's
+tiles.
 
 ``scale``, ``bias`` and ``activation`` form the exact-mode epilogue
-``activation(out * scale + bias)``; on the non-fused paths it is applied
-as a follow-up XLA elementwise pass so all exact paths share a single
+``activation(out * scale + bias)``; on the reference path it is applied
+as a follow-up XLA elementwise pass so both exact paths share a single
 calling convention.
 """
 
@@ -33,12 +28,10 @@ import jax.numpy as jnp
 
 from repro.core.formats import E4M3, FPFormat, encode_bits
 from repro.parallel.sharding import replicated_kernel_call
-from . import mgs_matmul as _mm
 from . import ref as _ref
 from .mgs_matmul import (ACTIVATIONS, default_interpret, fused_tiles,
                          mgs_matmul_dmac_pallas,
-                         mgs_matmul_exact_fused_pallas,
-                         mgs_matmul_exact_pallas, ws_stripe_bytes)
+                         mgs_matmul_exact_fused_pallas)
 
 __all__ = ["mgs_matmul", "apply_epilogue"]
 
@@ -83,64 +76,26 @@ def apply_epilogue(out, scale, bias, activation: str):
     return ACTIVATIONS[activation](out)
 
 
-def _fused_schedule(schedule: str, K: int, block_m: int, block_n: int,
-                    block_k: int) -> str:
-    """Validate/downgrade the fused-kernel schedule for this shape.
-
-    The stationary schedules keep a 3 x Kp x block int8 decoded limb
-    stripe resident in VMEM ("weight": block_n across the M-grid axis;
-    "activation": block_m across the N-grid axis); shapes whose stripe
-    exceeds the budget fall back to the output-stationary schedule with a
-    warning (never silently, and never an error — the schedules are
-    bit-identical).
-    """
-    if schedule not in ("weight", "activation"):
-        return schedule
-    block = block_n if schedule == "weight" else block_m
-    stripe = ws_stripe_bytes(K, block, block_k)
-    # read the budget off the kernel module (one binding) so the hard
-    # check in mgs_matmul_exact_fused_pallas can never disagree
-    budget = _mm.WS_STRIPE_BUDGET_BYTES
-    if stripe > budget:
-        other = "grid_m x more in-kernel weight decode" \
-            if schedule == "weight" else \
-            "grid_n x more in-kernel activation decode"
-        warnings.warn(
-            f"{schedule}-stationary schedule: K={K}, block={block} needs "
-            f"a {stripe / 2**20:.1f} MB K-resident limb stripe (> "
-            f"{budget / 2**20:.0f} MB VMEM budget); "
-            "falling back to the output-stationary schedule "
-            f"(bit-identical, {other}).",
-            stacklevel=3)
-        return "output"
-    return schedule
-
-
 def mgs_matmul(x, w, fmt: FPFormat = E4M3, mode: str = "exact", *,
-               use_kernel: bool = True, fused: bool = False,
-               gate_subnormal: bool = True, block_m: int = 128,
-               block_n: int = 128, block_k: int = 128,
-               flush_period: int | None = None, schedule: str = "output",
-               scale=None, bias=None,
+               use_kernel: bool = True, gate_subnormal: bool = True,
+               block_m: int = 128, block_n: int = 128, block_k: int = 128,
+               flush_period: int | None = None, scale=None, bias=None,
                activation: str = "none", interpret: bool | None = None):
     """MGS quantized matmul: (..., K) @ (K, N) with MGS numerics.
 
     ``x`` must hold format-exact FP8 values (see quant.quantize_fp8).
     ``w`` is either a (K, N) array of format-exact values or a
     ``quant.prepared.PreparedWeight`` (duck-typed: anything with
-    ``codes`` / ``limbs`` / ``values()``), whose cached planes feed the
-    kernels without per-call re-quantization.
+    ``codes`` / ``values()``), whose cached planes feed the kernels
+    without per-call re-quantization.
 
     ``scale``/``bias``/``activation`` (exact mode only) apply
     ``activation(out * scale + bias)`` — inside the kernel when
-    ``fused=True``, as a follow-up elementwise pass otherwise.
-    ``schedule`` selects the fused kernel's loop order ("output" /
-    "weight" / "activation" — see ``mgs_matmul_exact_fused_pallas``);
-    oversized stationary stripes fall back to "output" with a warning.
+    ``use_kernel``, as a follow-up elementwise pass otherwise.
     """
     if interpret is None:
         interpret = default_interpret()
-    prepared = hasattr(w, "codes") and hasattr(w, "limbs")
+    prepared = hasattr(w, "codes")
     ix_bits = fmt.mbits + 1 + fmt.emax  # fixed-point width of sm << e
     if mode == "exact" and ix_bits > 21:
         # The 3x7-bit limb scheme needs ix = sm << e to fit ~20 bits;
@@ -166,34 +121,20 @@ def mgs_matmul(x, w, fmt: FPFormat = E4M3, mode: str = "exact", *,
         w_vals = w.values() if prepared else w
         out = _ref.mgs_matmul_ref(x2, w_vals, fmt, mode, gate_subnormal)
         out = apply_epilogue(out, scale, bias, activation)
-    elif mode == "exact" and fused:
+    elif mode == "exact":
         xc = x2 if x2.dtype == jnp.uint8 else encode_bits(x2, fmt)
         wc = w.codes if prepared else encode_bits(w, fmt)
-        sched = _fused_schedule(schedule, K, block_m, block_n, block_k)
-        if sched == "output":
-            # tiles from the call's shape; a runtime flush period counts
-            # K-steps of the caller's block_k, which then stays the K tile
-            block_m, block_n, block_k = fused_tiles(
-                x2.shape[0], K, n_out,
-                flush_block_k=None if flush_period is None else block_k)
+        # tiles from the call's shape; a runtime flush period counts
+        # K-steps of the caller's block_k, which then stays the K tile
+        block_m, block_n, block_k = fused_tiles(
+            x2.shape[0], K, n_out,
+            flush_block_k=None if flush_period is None else block_k)
         out = replicated_kernel_call(
             lambda xc, wc, s, b, fp: mgs_matmul_exact_fused_pallas(
                 xc, wc, fmt, scale=s, bias=b, activation=activation,
                 block_m=block_m, block_n=block_n, block_k=block_k,
-                flush_period=fp, schedule=sched, interpret=interpret),
+                flush_period=fp, interpret=interpret),
             xc, wc, scale, bias, flush_period)
-    elif mode == "exact":
-        # prepared weights without resident limb planes (built for a fused
-        # config) fall back to decoding values from the packed codes
-        w_limbs = w.limbs if prepared else None
-        w_vals = None if w_limbs is not None else (
-            w.values() if prepared else w)
-        out = replicated_kernel_call(
-            lambda x2, wv, wl, fp: mgs_matmul_exact_pallas(
-                x2, wv, fmt, w_limbs=wl, block_m=block_m, block_n=block_n,
-                block_k=block_k, flush_period=fp, interpret=interpret),
-            x2, w_vals, w_limbs, flush_period)
-        out = apply_epilogue(out, scale, bias, activation)
     elif mode == "dmac":
         bm, bn = _dmac_block_shapes(block_m, block_n, block_k)
         w_vals = w.values() if prepared else w

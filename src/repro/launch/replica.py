@@ -15,8 +15,8 @@ aggregate requests/sec scales with R
 (``benchmarks/replica_throughput.py``).
 
 Weight state is built **once** and shared: replica 0 prepares the
-quantized planes (``quant.prepare_params`` — packed codes, limb planes,
-scales, the cached unembedding view), and the remaining replicas receive
+quantized planes (``quant.prepare_params`` — packed codes, scales,
+the cached unembedding view), and the remaining replicas receive
 ``device_put`` transfers of the same planes onto their sub-meshes — zero
 re-quantization, counted by ``quant.PREP_STATS`` staying flat in R
 (``tests/test_replica.py``). Calibration is likewise one pass:

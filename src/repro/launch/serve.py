@@ -143,8 +143,8 @@ class ServeEngine:
 
     On a multi-device ``mesh`` the engine prepares each weight *directly
     into its sharded layout*: plane PartitionSpecs are derived from the
-    weight's logical dims (``parallel.sharding.prepared_specs`` — codes
-    and limb planes inherit the weight's (in, out) layout, per-channel
+    weight's logical dims (``parallel.sharding.prepared_specs`` — the
+    codes plane inherits the weight's (in, out) layout, per-channel
     scales follow the out dim), and the remaining raw parameters
     (embeddings, norms, conv filters) are placed by the same serve
     rules. Every model matmul — including the attention out-projection,
@@ -1330,8 +1330,8 @@ def main():
         from repro.quant.config import FP8_MGS_SERVE_PAGED
         q = FP8_MGS_SERVE_PAGED
         if args.reduced:    # CPU-friendly tiles + jnp reference path
-            q = q.replace(use_kernel=False, fused=False,
-                          block_m=32, block_n=32, block_k=32)
+            q = q.replace(use_kernel=False, block_m=32, block_n=32,
+                          block_k=32)
         if args.spec_k:
             q = q.replace(draft_layers=args.draft_layers
                           or max(1, cfg.n_layers // 2))

@@ -284,10 +284,9 @@ def named_sharding(spec_tree, mesh: Mesh):
 # ---------------------------------------------------------------------------
 #
 # A ``quant.prepared.PreparedWeight`` stores a (*stack, K, *tail) weight as
-# three kernel-ready planes whose trailing output axes are *flattened*:
+# two kernel-ready planes whose trailing output axes are *flattened*:
 #
 #   codes  (*stack, K, n)        packed FP8 codes, n = prod(tail)
-#   limbs  (*stack, 3, K, n)     balanced int8 limb planes (optional)
 #   scale  (*stack, 1, n) | (*stack,)   per-channel | per-tensor scales
 #
 # The planes must live on the mesh exactly where the owning weight's
@@ -323,12 +322,12 @@ def prepared_plane_dims(w_dims: Tuple[Optional[str], ...], rules: Rules, *,
         it could split a head, and the exact kernel consumes K whole).
 
     Returns:
-      ``(codes_dims, limbs_dims, out_dim)``: dims tuples for the codes
-      and limbs planes, and the logical name chosen for the flattened
-      output axis. Only the *leading* tail dim may name it: a chunk of
-      the flattened axis then covers whole trailing slices (e.g. whole
-      heads), so the plane layout stays aligned with the raw weight's.
-      ``None`` when the leading tail dim has no mesh candidates.
+      ``(codes_dims, out_dim)``: the dims tuple of the codes plane, and
+      the logical name chosen for the flattened output axis. Only the
+      *leading* tail dim may name it: a chunk of the flattened axis then
+      covers whole trailing slices (e.g. whole heads), so the plane
+      layout stays aligned with the raw weight's. ``None`` when the
+      leading tail dim has no mesh candidates.
     """
     n_stack = (1 if stacked else 0) if stack_ndim is None else stack_ndim
     stack_dims = tuple(w_dims[:n_stack])
@@ -338,9 +337,7 @@ def prepared_plane_dims(w_dims: Tuple[Optional[str], ...], rules: Rules, *,
     if tail_dims and tail_dims[0] is not None and rules.table.get(
             tail_dims[0]):
         out_dim = tail_dims[0]
-    codes_dims = stack_dims + (in_dim, out_dim)
-    limbs_dims = stack_dims + (None, in_dim, out_dim)  # 3-limb axis local
-    return codes_dims, limbs_dims, out_dim
+    return stack_dims + (in_dim, out_dim), out_dim
 
 
 def prepared_specs(w_dims: Tuple[Optional[str], ...],
@@ -368,8 +365,8 @@ def prepared_specs(w_dims: Tuple[Optional[str], ...],
         shape ``(*stack, 1, n)`` (else per-tensor, shape ``(*stack,)``).
 
     Returns:
-      ``(codes_spec, limbs_spec, scale_spec)`` PartitionSpecs, shaped for
-      the corresponding plane ranks (specs over the flattened ``n`` axis
+      ``(codes_spec, scale_spec)`` PartitionSpecs, shaped for the
+      corresponding plane ranks (specs over the flattened ``n`` axis
       — an axis dividing the leading tail dim also divides ``n``).
     """
     n_stack = (1 if stacked else 0) if stack_ndim is None else stack_ndim
@@ -379,13 +376,12 @@ def prepared_specs(w_dims: Tuple[Optional[str], ...],
         K *= int(s)
     tail = tuple(int(s) for s in w_shape[n_stack + k_ndim:])
     out_size = tail[0] if tail else 1
-    codes_dims, limbs_dims, out_dim = prepared_plane_dims(
+    codes_dims, out_dim = prepared_plane_dims(
         w_dims, rules, stack_ndim=n_stack, k_ndim=k_ndim)
     codes_spec = rules.resolve(codes_dims, stack_shape + (K, out_size))
-    limbs_spec = rules.resolve(limbs_dims, stack_shape + (3, K, out_size))
     if per_channel:
         scale_spec = rules.resolve(tuple(w_dims[:n_stack]) + (None, out_dim),
                                    stack_shape + (1, out_size))
     else:
         scale_spec = rules.resolve(tuple(w_dims[:n_stack]), stack_shape)
-    return codes_spec, limbs_spec, scale_spec
+    return codes_spec, scale_spec

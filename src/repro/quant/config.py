@@ -16,11 +16,10 @@ from typing import Optional, Tuple
 
 from repro.core.formats import E4M3, E5M2, FPFormat, get_format
 
-__all__ = ["QuantConfig", "DTYPES", "ACCUMS", "SCHEDULES", "KV_CACHES"]
+__all__ = ["QuantConfig", "DTYPES", "ACCUMS", "KV_CACHES"]
 
 DTYPES = ("none", "int8", "int5", "int4", "fp8_e4m3", "fp8_e5m2")
 ACCUMS = ("wide", "mgs_exact", "mgs_dmac", "clip", "wrap", "swamp")
-SCHEDULES = ("output", "weight", "activation")
 KV_CACHES = ("float", "packed")
 # Narrow-exponent formats the exact limb kernels support; the packed KV
 # cache decode runs through them, so kv_format is restricted to this set.
@@ -51,33 +50,19 @@ class QuantConfig:
         by default — per-tensor is the baseline numerics every existing
         pin test is anchored to.
       gate_subnormal: §5.3 subnormal gating of tiny products.
-      use_kernel: route through the Pallas kernel (TPU target; tests run it
-        in interpret mode). False = pure-jnp emulation path (XLA-compiled,
-        used by the CPU dry-run).
-      fused: exact-mode kernel variant. True streams *packed* FP8 codes
-        (1 byte/elem HBM) and decodes + limb-splits per tile in VMEM, with
-        the dequant-scale/bias/activation epilogue fused into the kernel;
-        False streams pre-decomposed int8 limb planes (3 bytes/elem, the
-        A/B baseline).
-      schedule: fused-kernel loop order. "output" (default) is
-        output-stationary: both operand tiles are decoded at every grid
-        step. "weight" is the K-resident weight-stationary schedule: the
-        decoded weight limb stripe is cached in VMEM scratch across the
-        M-grid axis, cutting in-kernel weight decode work grid_m-fold.
-        "activation" is the symmetric activation-stationary schedule:
-        the decoded x limb stripe is cached across the N-grid axis,
-        cutting activation decode work grid_n-fold (wide-N layers). All
-        three are bit-identical; stationary schedules fall back to
-        "output" with a warning when the stripe exceeds the VMEM budget.
-      block_m/n/k: Pallas tiles of the non-fused kernels (the
-        pre-decomposed exact kernel, dmac) and of the fused kernel's
-        stationary schedules. The fused output-stationary kernel reads
-        its tiles off each call's shape
+      use_kernel: route through the Pallas kernels (TPU target; tests run
+        them in interpret mode). False = pure-jnp emulation path
+        (XLA-compiled reference). With ``accum="mgs_exact"`` the kernel
+        streams *packed* FP8 codes (1 byte/elem HBM) and decodes +
+        limb-splits per tile in VMEM, with the
+        dequant-scale/bias/activation epilogue fused into the kernel.
+      block_m/n/k: Pallas tiles of the dmac kernel. The exact kernel
+        reads its tiles off each call's shape
         (``kernels.mgs_matmul.fused_tiles``) and ignores block_m/n.
         block_k is besides the paged KV cache's page (block) size, the
         flash-attention chunk, and the unit of a runtime flush period
         (``flush_target`` or a calibrated flush entry counts K-steps of
-        block_k): under such a period the fused kernel keeps block_k as
+        block_k): under such a period the exact kernel keeps block_k as
         its K tile, so its flushes fall at the planned K positions.
       flush_target: probabilistic overflow budget used by the Markov
         planner (core.markov.plan_flush_period) to derive the kernel flush
@@ -129,8 +114,6 @@ class QuantConfig:
     per_row_act: bool = False
     gate_subnormal: bool = True
     use_kernel: bool = False
-    fused: bool = False
-    schedule: str = "output"
     block_m: int = 128
     block_n: int = 128
     block_k: int = 128
@@ -149,9 +132,6 @@ class QuantConfig:
             raise ValueError(f"dtype {self.dtype!r} not in {DTYPES}")
         if self.accum not in ACCUMS:
             raise ValueError(f"accum {self.accum!r} not in {ACCUMS}")
-        if self.schedule not in SCHEDULES:
-            raise ValueError(f"schedule {self.schedule!r} not in "
-                             f"{SCHEDULES}")
         if self.kv_cache not in KV_CACHES:
             raise ValueError(f"kv_cache {self.kv_cache!r} not in "
                              f"{KV_CACHES}")
@@ -221,8 +201,7 @@ class QuantConfig:
     @property
     def fused_exact(self) -> bool:
         """True when matmuls run the streaming limb-fused exact kernel."""
-        return (self.is_fp8 and self.accum == "mgs_exact"
-                and self.use_kernel and self.fused)
+        return self.is_fp8 and self.accum == "mgs_exact" and self.use_kernel
 
     def act_sigma(self, site: Optional[str]) -> Optional[float]:
         """Observed activation limb sigma for a call site, or None."""
@@ -259,13 +238,12 @@ FP8_MGS_EXACT = QuantConfig(dtype="fp8_e4m3", accum="mgs_exact")
 # Serving preset: streaming limb-fused kernel over packed codes with
 # prepared weights (see quant.prepared) and fused epilogues.
 FP8_MGS_SERVE = QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                            use_kernel=True, fused=True)
+                            use_kernel=True)
 # Serving preset with the packed FP8 KV cache: decode attention streams
 # 1-byte cache codes through the MGS flash-decode kernel
 # (kernels.mgs_attention), halving decode HBM traffic vs a bf16 cache.
 FP8_MGS_SERVE_KV = QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                               use_kernel=True, fused=True,
-                               kv_cache="packed")
+                               use_kernel=True, kv_cache="packed")
 # Continuous-batching serving preset: packed cache + per-row activation
 # scales, making every decode step row-independent — the numerics the
 # paged slot engine (launch.serve.ContinuousBatchingEngine) requires for

@@ -1,20 +1,20 @@
-"""Prepared weights: quantize + limb-decompose static parameters *once*.
+"""Prepared weights: quantize + encode static parameters *once*.
 
 The serving hot path must not re-quantize weights per request: a static
-weight's absmax scale, packed FP8 codes, and int8 limb planes are all
-functions of the parameter alone, so they are computed once — at load /
-checkpoint / engine-init time — and cached for the life of the process.
-``qmatmul`` then consumes the :class:`PreparedWeight` directly:
+weight's absmax scale and packed FP8 codes are functions of the
+parameter alone, so they are computed once — at load / checkpoint /
+engine-init time — and cached for the life of the process. ``qmatmul``
+then consumes the :class:`PreparedWeight` directly:
 
-* fused exact kernel: streams ``codes`` (1 byte/element of HBM traffic);
-* pre-decomposed exact kernel: streams ``limbs`` (the A/B baseline);
+* exact kernel: streams ``codes`` (1 byte/element of HBM traffic) and
+  decodes its limbs per tile in VMEM;
 * emulation / dmac fallbacks: reconstruct format-exact values from
   ``codes`` via ``decode_bits`` (cheap elementwise, no re-rounding).
 
 ``PreparedWeight`` is a registered pytree whose leaves are arrays, so a
 prepared parameter tree passes through ``jax.jit`` / ``lax.scan`` like any
 other: model code that scans stacked per-layer weights slices the codes /
-limbs / scale planes along the leading layer axis transparently.
+scale planes along the leading layer axis transparently.
 
 ``prepare_weight`` keeps a process-level cache keyed by parameter
 identity; ``PREP_STATS`` counts builds vs cache hits so tests (and
@@ -23,7 +23,7 @@ monitoring) can verify each weight is prepared exactly once per process.
 On a multi-device mesh the planes are built **directly into their sharded
 layout**: pass ``shardings`` (one :class:`jax.sharding.NamedSharding` per
 plane, usually derived via :func:`repro.parallel.sharding.prepared_specs`)
-and the quantize+decompose computation is jitted with those
+and the quantize+encode computation is jitted with those
 ``out_shardings`` — no full replicated copy of the planes ever
 materializes, and re-preparation on the same mesh is a cache hit like any
 other. ``prepare_params(..., dims=..., rules=...)`` derives the plane
@@ -50,7 +50,7 @@ __all__ = ["PreparedWeight", "prepare_weight", "prepare_params",
            "clear_prepared_cache"]
 
 # Process-level preparation accounting: ``prepared`` counts actual
-# quantize+decompose builds, ``cache_hits`` counts reuses. Serving must
+# quantize+encode builds, ``cache_hits`` counts reuses. Serving must
 # keep ``prepared`` constant across requests.
 PREP_STATS = {"prepared": 0, "cache_hits": 0}
 
@@ -58,17 +58,13 @@ _CACHE: dict = {}
 
 
 class PreparedWeight:
-    """A weight quantized + limb-decomposed once, in kernel-ready planes.
+    """A weight quantized + encoded once, in kernel-ready planes.
 
     Array leaves (pytree children — all share any leading stack axes):
 
     * ``codes``: packed FP8 codes (uint8), shape (*stack, K, N) — the
-      fused kernel's 1-byte/elem HBM stream, and the source for
-      :meth:`values` on the emulation paths. Always materialized.
-    * ``limbs``: balanced int8 limb planes, shape (*stack, 3, K, N) — the
-      pre-decomposed kernel's input. ``None`` unless the config actually
-      streams them (``use_kernel and not fused``): at 3 bytes/elem they
-      would otherwise sit as dead device memory next to the codes.
+      exact kernel's 1-byte/elem HBM stream, and the source for
+      :meth:`values` on the emulation paths.
     * ``scale``: dequantization scale, broadcastable to (*stack, 1, N).
 
     The stack may span several leading axes (``stack_ndim`` — e.g.
@@ -85,11 +81,10 @@ class PreparedWeight:
     (``quant.calibrate``; ``None`` until a calibration pass stamps it).
     """
 
-    def __init__(self, codes, limbs, scale, fmt_name: str,
+    def __init__(self, codes, scale, fmt_name: str,
                  tail: Tuple[int, ...], limb_sigma: Optional[float] = None,
                  act_sigma: Optional[float] = None):
         self.codes = codes
-        self.limbs = limbs
         self.scale = scale
         self.fmt_name = fmt_name
         self.tail = tuple(tail)
@@ -110,8 +105,8 @@ class PreparedWeight:
 
     def with_act_sigma(self, act_sigma: Optional[float]) -> "PreparedWeight":
         """Copy sharing the same planes, with a calibrated act sigma."""
-        return PreparedWeight(self.codes, self.limbs, self.scale,
-                              self.fmt_name, self.tail, self.limb_sigma,
+        return PreparedWeight(self.codes, self.scale, self.fmt_name,
+                              self.tail, self.limb_sigma,
                               act_sigma=act_sigma)
 
     def __repr__(self):
@@ -122,14 +117,14 @@ class PreparedWeight:
 
 
 def _pw_flatten(pw: PreparedWeight):
-    return ((pw.codes, pw.limbs, pw.scale),
+    return ((pw.codes, pw.scale),
             (pw.fmt_name, pw.tail, pw.limb_sigma, pw.act_sigma))
 
 
 def _pw_unflatten(aux, children):
-    codes, limbs, scale = children
+    codes, scale = children
     fmt_name, tail, limb_sigma, act_sigma = aux
-    return PreparedWeight(codes, limbs, scale, fmt_name, tail, limb_sigma,
+    return PreparedWeight(codes, scale, fmt_name, tail, limb_sigma,
                           act_sigma=act_sigma)
 
 
@@ -137,7 +132,7 @@ jax.tree_util.register_pytree_node(PreparedWeight, _pw_flatten, _pw_unflatten)
 
 
 def _build(w, cfg: QuantConfig, stack_ndim: int, k_ndim: int,
-           keep_limbs: bool, shardings=None) -> PreparedWeight:
+           shardings=None) -> PreparedWeight:
     fmt = cfg.fmt
     w = jnp.asarray(w)
     if stack_ndim + k_ndim >= w.ndim and not (
@@ -170,14 +165,10 @@ def _build(w, cfg: QuantConfig, stack_ndim: int, k_ndim: int,
         limbs = limb_decompose(qt.q, fmt)     # (3, *stack, K, n)
         if stack:
             limbs = jnp.moveaxis(limbs, 0, len(stack))  # (*stack, 3, K, n)
-        # observed limb statistics feed the Markov flush planner even when
-        # the limb planes themselves are not kept resident — and when they
-        # are not, the plane is not a jit output, so XLA fuses the
-        # decompose into the std reduction instead of materializing a
-        # 3-byte/elem buffer that would be dropped immediately.
+        # observed limb statistics feed the Markov flush planner; the
+        # limbs are not a jit output, so XLA fuses the decompose into the
+        # std reduction instead of materializing a 3-byte/elem buffer.
         sigma = jnp.std(limbs.astype(jnp.float32))
-        if keep_limbs:
-            return codes, limbs, qt.scale, sigma
         return codes, qt.scale, sigma
 
     # one jitted program per weight: its float/int32 temporaries are
@@ -188,25 +179,17 @@ def _build(w, cfg: QuantConfig, stack_ndim: int, k_ndim: int,
     if shardings is not None:
         # build straight into the mesh layout: the planes come out of the
         # jit already sharded — never materialized replicated-then-moved.
-        codes_sh, limbs_sh, scale_sh = shardings
-        jit_kw["out_shardings"] = ((codes_sh, limbs_sh, scale_sh, None)
-                                   if keep_limbs
-                                   else (codes_sh, scale_sh, None))
-    limbs = None
-    if keep_limbs:
-        codes, limbs, scale, sigma = jax.jit(compute, **jit_kw)(w)
-    else:
-        codes, scale, sigma = jax.jit(compute, **jit_kw)(w)
+        codes_sh, scale_sh = shardings
+        jit_kw["out_shardings"] = (codes_sh, scale_sh, None)
+    codes, scale, sigma = jax.jit(compute, **jit_kw)(w)
     PREP_STATS["prepared"] += 1
-    return PreparedWeight(codes, limbs, scale, fmt.name, tuple(tail),
-                          float(sigma))
+    return PreparedWeight(codes, scale, fmt.name, tuple(tail), float(sigma))
 
 
 def prepare_weight(w, cfg: QuantConfig, *, stacked: bool = False,
                    stack_ndim: Optional[int] = None, k_ndim: int = 1,
-                   keep_limbs: Optional[bool] = None,
                    shardings=None) -> PreparedWeight:
-    """Quantize + decompose ``w`` under ``cfg``, cached per process.
+    """Quantize + encode ``w`` under ``cfg``, cached per process.
 
     Args:
       w: ``(*stack, *kdims, *tail)`` weight. Stack axes (per-layer,
@@ -219,11 +202,7 @@ def prepare_weight(w, cfg: QuantConfig, *, stacked: bool = False,
       stack_ndim: number of leading per-slice stack axes (overrides
         ``stacked``; e.g. 2 for (layers, experts) MoE expert weights).
       k_ndim: number of contracted axes following the stack (default 1).
-      keep_limbs: keep the 3-byte/elem pre-decomposed planes resident;
-        default: only when ``cfg`` streams them (``use_kernel and not
-        fused``). Paths that find them missing fall back to the packed
-        codes.
-      shardings: optional ``(codes, limbs, scale)`` triple of
+      shardings: optional ``(codes, scale)`` pair of
         :class:`jax.sharding.NamedSharding` (see
         :func:`repro.parallel.sharding.prepared_specs`). When given, the
         planes are built directly into that mesh layout via jit
@@ -242,16 +221,13 @@ def prepare_weight(w, cfg: QuantConfig, *, stacked: bool = False,
                          f"{cfg.dtype!r}")
     if stack_ndim is None:
         stack_ndim = 1 if stacked else 0
-    if keep_limbs is None:
-        keep_limbs = cfg.use_kernel and not cfg.fused
     key = (id(w), cfg.dtype, cfg.accum, cfg.per_channel, int(stack_ndim),
-           int(k_ndim), bool(keep_limbs),
-           None if shardings is None else tuple(shardings))
+           int(k_ndim), None if shardings is None else tuple(shardings))
     hit = _CACHE.get(key)
     if hit is not None and hit[0]() is w:
         PREP_STATS["cache_hits"] += 1
         return hit[1]
-    pw = _build(w, cfg, stack_ndim, k_ndim, keep_limbs, shardings)
+    pw = _build(w, cfg, stack_ndim, k_ndim, shardings)
     try:
         # weak ref: cache validity without pinning the raw weight (the
         # prepared planes replace it in the serving path)
@@ -269,7 +245,7 @@ def prepare_unembed(embed, cfg: QuantConfig, *,
     parameter tree (the token-lookup path needs raw rows), so the logits
     head used to re-quantize the whole table on *every* prefill/decode
     step — the largest per-token re-quantization left in serving. This
-    helper quantizes + decomposes the **transposed** ``(d_model, vocab)``
+    helper quantizes + encodes the **transposed** ``(d_model, vocab)``
     view once — canonical ``(K, N)`` planes the logits head's
     ``qeinsum("btd,dv->btv", ...)`` consumes directly — and caches the
     result per process keyed on the *embedding table's* identity (the
@@ -279,7 +255,7 @@ def prepare_unembed(embed, cfg: QuantConfig, *,
       embed: the raw ``(vocab, d_model)`` embedding table.
       cfg: fp8 quantization config (same contract as
         :func:`prepare_weight`).
-      shardings: optional ``(codes, limbs, scale)`` NamedShardings for
+      shardings: optional ``(codes, scale)`` NamedShardings for
         the **(d_model, vocab)-shaped view** — derive them from the
         logical dims ``("embed", "vocab")``, e.g. via
         :func:`repro.parallel.sharding.prepared_specs`.
@@ -296,16 +272,14 @@ def prepare_unembed(embed, cfg: QuantConfig, *,
     if getattr(embed, "ndim", 0) != 2:
         raise ValueError(f"embedding table must be 2D, got shape "
                          f"{getattr(embed, 'shape', None)}")
-    keep_limbs = cfg.use_kernel and not cfg.fused
     key = ("unembed", id(embed), cfg.dtype, cfg.accum, cfg.per_channel,
-           bool(keep_limbs),
            None if shardings is None else tuple(shardings))
     hit = _CACHE.get(key)
     if hit is not None and hit[0]() is embed:
         PREP_STATS["cache_hits"] += 1
         return hit[1]
     view = jnp.swapaxes(jnp.asarray(embed), 0, 1)   # (V, d) -> (d, V)
-    pw = _build(view, cfg, 0, 1, keep_limbs, shardings)
+    pw = _build(view, cfg, 0, 1, shardings)
     try:
         _CACHE[key] = (weakref.ref(embed), pw)
     except TypeError:

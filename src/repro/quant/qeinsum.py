@@ -266,8 +266,6 @@ def qeinsum(spec: str, x, w, cfg: QuantConfig, *, dims=None,
                      else jnp.broadcast_to(w.scale, (B,)))
             wb = PreparedWeight(
                 w.codes.reshape((B,) + w.codes.shape[-2:]),
-                None if w.limbs is None else
-                w.limbs.reshape((B,) + w.limbs.shape[-3:]),
                 scale, w.fmt_name, w.tail, w.limb_sigma,
                 act_sigma=w.act_sigma)
         else:

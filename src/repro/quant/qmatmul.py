@@ -12,9 +12,9 @@ runs the configured numerics, and rescales:
   fp8_* + accum=wide          -> FP8 operands, fp32 accumulation (H100/TPU
                                  baseline the paper compares against)
   fp8_* + accum=mgs_exact     -> exact fixed-point accumulation
-                                 (Pallas limb kernel / jnp reference);
-                                 cfg.fused streams packed codes with the
-                                 scale/bias/activation epilogue in-kernel
+                                 (Pallas limb kernel over packed codes,
+                                 scale/bias/activation epilogue
+                                 in-kernel / jnp reference)
   fp8_* + accum=mgs_dmac      -> paper-faithful Fig. 8 numerics
   fp8_* + accum=swamp         -> sequential narrow accumulator (failure
                                  baseline, Fig. 3)
@@ -25,7 +25,7 @@ runs the configured numerics, and rescales:
 Weights may be passed as ``quant.prepared.PreparedWeight`` — quantized +
 limb-decomposed once per process (at load/engine-init time) — in which
 case no weight quantization happens here: the serving hot path re-uses the
-cached scale / packed codes / limb planes on every call. The Markov flush
+cached scale / packed codes on every call. The Markov flush
 planner kicks in when ``cfg.flush_target`` is set, using the prepared
 weight's observed limb statistics to lengthen the exact kernel's flush
 period beyond the worst-case bound.
@@ -134,13 +134,12 @@ def qmatmul(x, w, cfg: QuantConfig, out_dtype=None, *, bias=None,
                 in_kernel_epi = not cfg.per_row_act
                 out = kops.mgs_matmul(
                     qx.q, w_arg, fmt, mode, use_kernel=cfg.use_kernel,
-                    fused=cfg.fused, gate_subnormal=cfg.gate_subnormal,
+                    gate_subnormal=cfg.gate_subnormal,
                     block_m=cfg.block_m, block_n=cfg.block_n,
                     block_k=cfg.block_k,
                     flush_period=_exact_flush_period(
                         cfg, w.limb_sigma if prepared else None, x_sigma,
                         site),
-                    schedule=cfg.schedule,
                     scale=scale if in_kernel_epi else None,
                     bias=bias if in_kernel_epi else None,
                     activation=activation if in_kernel_epi else "none")

@@ -1,7 +1,7 @@
 """Output-stationary fused matmul tiles chosen from the call's shape.
 
 ``kernels.mgs_matmul.fused_tiles`` picks ``(block_m, block_n, block_k)``
-for an ``(M, K) @ (K, N)`` call; ``ops.mgs_matmul``'s fused branch uses
+for an ``(M, K) @ (K, N)`` call; ``ops.mgs_matmul``'s exact branch uses
 it. The limb sums are exact under any partition, so the chosen tiles
 must give the very bits of the 128x128x128 tiling, flush for flush.
 The chooser tests run no kernel: they hold its choices for deepseek-7b's
@@ -57,7 +57,7 @@ BITWISE = {
 @pytest.mark.parametrize("case", list(BITWISE), ids=list(BITWISE))
 def test_chosen_tiles_bitwise_equal_128_tiles(rng, case):
     """The kernel at ``fused_tiles``' tiles, called directly and through
-    ``ops.mgs_matmul``'s fused branch, equals the 128x128x128 call bit
+    ``ops.mgs_matmul``'s exact branch, equals the 128x128x128 call bit
     for bit; a runtime flush period keeps the caller's 128-wide K tile
     and so flushes at the same K positions."""
     M, K, N, opt = BITWISE[case]
@@ -78,7 +78,7 @@ def test_chosen_tiles_bitwise_equal_128_tiles(rng, case):
                                         block_k=bk, flush_period=fp,
                                         interpret=True, **epi)
     np.testing.assert_array_equal(np.asarray(got), want)
-    via_ops = ops.mgs_matmul(xc, _Codes(wc), _F, "exact", fused=True,
+    via_ops = ops.mgs_matmul(xc, _Codes(wc), _F, "exact",
                              block_m=128, block_n=128, block_k=128,
                              flush_period=fp, interpret=True, **epi)
     np.testing.assert_array_equal(np.asarray(via_ops), want)
@@ -88,15 +88,15 @@ class _Codes:
     """A prepared-weight stand-in: ``ops.mgs_matmul`` streams ``codes``."""
 
     def __init__(self, codes):
-        self.codes, self.limbs = codes, None
+        self.codes = codes
 
 
 def test_tile_stats_records_the_call(rng):
-    """The fused branch records each traced call shape with its tiles
+    """The exact branch records each traced call shape with its tiles
     and grid steps: 40 rows are one tile of 64, K = 768 = 6 x 128 takes
     384, N = 1024 takes 512."""
     xc, wc = _codes(rng, (40, 768)), _codes(rng, (768, 1024))
-    ops.mgs_matmul(xc, _Codes(wc), _F, "exact", fused=True, interpret=True)
+    ops.mgs_matmul(xc, _Codes(wc), _F, "exact", interpret=True)
     assert TILE_STATS[(40, 768, 1024)] == (64, 512, 384, 1 * 2 * 2)
 
 

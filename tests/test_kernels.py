@@ -8,6 +8,7 @@ import pytest
 from repro.core import formats
 from repro.kernels import ops, ref
 from repro.kernels.mgs_matmul import (limb_decompose,
+                                      mgs_matmul_exact_fused_pallas,
                                       worst_case_flush_period)
 
 
@@ -71,15 +72,18 @@ def test_kernel_batched_lhs(rng):
                                np.asarray(want))
 
 
-def test_flush_period_forces_multiple_flushes(rng):
-    """Exactness must survive mid-K flushes (narrow->wide spills)."""
+@pytest.mark.parametrize("flush_period", [1, 2, 3])
+def test_flush_period_forces_multiple_flushes(rng, flush_period):
+    """Exactness must survive mid-K flushes (narrow->wide spills), every
+    K-step, every other one, and at a period that does not divide the
+    grid's 8 K-steps."""
     M, K, N = 8, 512, 8
     x = jnp.asarray(_fp8(rng, (M, K)))
     w = jnp.asarray(_fp8(rng, (K, N)))
-    from repro.kernels.mgs_matmul import mgs_matmul_exact_pallas
-    got = mgs_matmul_exact_pallas(x, w, formats.E4M3, block_m=8, block_n=8,
-                                  block_k=64, flush_period=2,
-                                  interpret=True)
+    got = mgs_matmul_exact_fused_pallas(
+        formats.encode_bits(x, formats.E4M3),
+        formats.encode_bits(w, formats.E4M3), formats.E4M3, block_m=8,
+        block_n=8, block_k=64, flush_period=flush_period, interpret=True)
     want = ref.mgs_matmul_ref(x, w, formats.E4M3, "exact")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6)

@@ -185,7 +185,7 @@ def test_quantized_cache_decode_logits_bitwise_kernel_vs_emulation(rng):
     outs = {}
     for use_kernel in (False, True):
         cfg = dataclasses.replace(
-            _packed_cfg(use_kernel=use_kernel, fused=use_kernel,
+            _packed_cfg(use_kernel=use_kernel,
                         block_m=32, block_n=32, block_k=32),
             compute_dtype="float32")
         cache, _ = init_cache(cfg, 2, 12)
@@ -336,7 +336,7 @@ def test_packed_cross_decode_bitwise_kernel_vs_emulation(rng):
     def tier(use_kernel):
         return dataclasses.replace(
             base, quant=dataclasses.replace(
-                base.quant, use_kernel=use_kernel, fused=use_kernel,
+                base.quant, use_kernel=use_kernel,
                 block_m=32, block_n=32, block_k=32))
 
     op = {}
@@ -474,7 +474,7 @@ def test_packed_cache_sharded_bit_identity():
 
     cfg = dataclasses.replace(reduced_config("deepseek-7b"),
         quant=QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                          use_kernel=True, fused=True, kv_cache="packed",
+                          use_kernel=True, kv_cache="packed",
                           block_m=32, block_n=32, block_k=32))
     params, dims = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -525,8 +525,7 @@ def test_native_packed_cache_bit_identity():
     from repro.launch.mesh import make_mesh, make_serve_mesh
     from repro.launch.serve import Request, ServeEngine
 
-    cfg = _packed_cfg(use_kernel=True, fused=True, block_m=32, block_n=32,
-                      block_k=32)
+    cfg = _packed_cfg(use_kernel=True, block_m=32, block_n=32, block_k=32)
     params, dims = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, cfg.vocab, 6).astype(np.int32)

@@ -1,9 +1,9 @@
 """Streaming fused kernel + PreparedWeight cache semantics.
 
-Covers the ISSUE-1 acceptance criteria: fused-kernel bit-identity against
-the jnp oracle and the pre-decomposed kernel (interpret mode), prepared
-weights matching per-call quantization exactly, cache-hit accounting, and
-scan-sliced stacked preparation (the transformer layer-stack layout).
+Fused-kernel bit-identity against the jnp oracle (interpret mode),
+prepared weights matching per-call quantization exactly, cache-hit
+accounting, and scan-sliced stacked preparation (the transformer
+layer-stack layout).
 """
 
 import dataclasses
@@ -16,8 +16,7 @@ import pytest
 from repro.core import formats
 from repro.kernels import ops, ref
 from repro.kernels.mgs_matmul import (limb_decompose,
-                                      mgs_matmul_exact_fused_pallas,
-                                      mgs_matmul_exact_pallas)
+                                      mgs_matmul_exact_fused_pallas)
 from repro.quant import (PREP_STATS, QuantConfig, prepare_params,
                          prepare_weight, qmatmul)
 
@@ -58,31 +57,6 @@ def test_fused_kernel_bit_identical_to_ref(rng, mkn):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("mkn", SHAPES[:3])
-def test_fused_kernel_bit_identical_to_unfused(rng, mkn):
-    M, K, N = mkn
-    x = jnp.asarray(_fp8(rng, (M, K)))
-    w = jnp.asarray(_fp8(rng, (K, N)))
-    fused = mgs_matmul_exact_fused_pallas(
-        formats.encode_bits(x, _F), formats.encode_bits(w, _F), _F,
-        block_m=32, block_n=32, block_k=64, interpret=True)
-    unfused = mgs_matmul_exact_pallas(x, w, _F, block_m=32, block_n=32,
-                                      block_k=64, interpret=True)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
-
-
-def test_fused_kernel_multiple_flushes(rng):
-    """Exactness must survive mid-K flushes (narrow->wide spills)."""
-    M, K, N = 8, 512, 8
-    x = jnp.asarray(_fp8(rng, (M, K)))
-    w = jnp.asarray(_fp8(rng, (K, N)))
-    got = mgs_matmul_exact_fused_pallas(
-        formats.encode_bits(x, _F), formats.encode_bits(w, _F), _F,
-        block_m=8, block_n=8, block_k=64, flush_period=2, interpret=True)
-    want = ref.mgs_matmul_ref(x, w, _F, "exact")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
-
-
 def test_fused_epilogue(rng):
     """activation(out * scale + bias), computed in-kernel.
 
@@ -114,100 +88,25 @@ def test_fused_rejects_unknown_activation(rng):
                                       interpret=True)
 
 
-@pytest.mark.parametrize("mkn", SHAPES)
-def test_weight_stationary_bit_identical(rng, mkn):
-    """The K-resident weight-stationary schedule caches decoded weight
-    limbs across the M-grid axis; results must stay bit-identical to the
-    output-stationary kernel and the jnp oracle."""
-    M, K, N = mkn
-    x = jnp.asarray(_fp8(rng, (M, K)))
-    w = jnp.asarray(_fp8(rng, (K, N)))
-    xc, wc = formats.encode_bits(x, _F), formats.encode_bits(w, _F)
-    ws = mgs_matmul_exact_fused_pallas(xc, wc, _F, block_m=32, block_n=32,
-                                       block_k=64, schedule="weight",
-                                       interpret=True)
-    want = ref.mgs_matmul_ref(x, w, _F, "exact")
-    np.testing.assert_array_equal(np.asarray(ws), np.asarray(want))
-
-
-def test_weight_stationary_epilogue_and_flush(rng):
-    """Schedules agree bit-for-bit with mid-K flushes + fused epilogue."""
-    M, K, N = 64, 512, 24
-    x = jnp.asarray(_fp8(rng, (M, K)))
-    w = jnp.asarray(_fp8(rng, (K, N)))
-    xc, wc = formats.encode_bits(x, _F), formats.encode_bits(w, _F)
-    scale = jnp.asarray(rng.uniform(0.5, 2.0, (1, N)).astype(np.float32))
-    bias = jnp.asarray(rng.normal(0, 1, (N,)).astype(np.float32))
-    for act in ("none", "gelu"):
-        kw = dict(scale=scale, bias=bias, activation=act, block_m=16,
-                  block_n=8, block_k=64, flush_period=2, interpret=True)
-        ws = mgs_matmul_exact_fused_pallas(xc, wc, _F, schedule="weight",
-                                           **kw)
-        os_ = mgs_matmul_exact_fused_pallas(xc, wc, _F, **kw)
-        np.testing.assert_array_equal(np.asarray(ws), np.asarray(os_))
-
-
-def test_weight_stationary_config_and_fallback(rng, monkeypatch):
-    """cfg.schedule plumbs through qmatmul; oversized K-resident stripes
-    fall back to the output schedule with a warning, never an error."""
-    import warnings
-
-    cfg_ws = dataclasses.replace(_CFG, fused=True, schedule="weight")
-    x = jnp.asarray(rng.normal(0, 1, (64, 96)).astype(np.float32))
-    w = jnp.asarray(rng.normal(0, 0.1, (96, 16)).astype(np.float32))
-    np.testing.assert_array_equal(
-        np.asarray(qmatmul(x, w, cfg_ws)),
-        np.asarray(qmatmul(x, w, dataclasses.replace(cfg_ws,
-                                                     schedule="output"))))
-    with pytest.raises(ValueError, match="schedule"):
-        dataclasses.replace(_CFG, schedule="diagonal")
-    from repro.kernels import mgs_matmul as mm, ops
-    monkeypatch.setattr(mm, "WS_STRIPE_BUDGET_BYTES", 1024)
-    xb = jnp.asarray(_fp8(rng, (8, 96)))
-    wb = jnp.asarray(_fp8(rng, (96, 8)))
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        out = ops.mgs_matmul(xb, wb, _F, "exact", fused=True,
-                             schedule="weight", block_m=8, block_n=32,
-                             block_k=32)
-    assert any("weight-stationary" in str(r.message) for r in rec)
-    np.testing.assert_array_equal(
-        np.asarray(out),
-        np.asarray(ops.mgs_matmul(xb, wb, _F, "exact", fused=True,
-                                  block_m=8, block_n=32, block_k=32)))
-
-
-def test_ops_dispatch_fused_matches_unfused(rng):
-    x = jnp.asarray(_fp8(rng, (2, 5, 96)))
-    w = jnp.asarray(_fp8(rng, (96, 24)))
-    fused = ops.mgs_matmul(x, w, _F, "exact", fused=True, block_m=32,
-                           block_n=32, block_k=32)
-    unfused = ops.mgs_matmul(x, w, _F, "exact", block_m=32, block_n=32,
-                             block_k=32)
-    assert fused.shape == (2, 5, 24)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
-
-
 # ---------------------------------------------------------------------------
 # PreparedWeight semantics
 # ---------------------------------------------------------------------------
 
 
-def test_prepared_matches_per_call_quantization(rng):
+SCALES = {"per-tensor": {}, "per-channel": dict(per_channel=True),
+          "per-row": dict(per_row_act=True)}
+
+
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "emulation"])
+@pytest.mark.parametrize("scales", list(SCALES))
+def test_prepared_matches_per_call_quantization(rng, scales, use_kernel):
+    """Prepared planes give the bits of quantizing the raw weight per
+    call, on the kernel and the emulation path, under every scale
+    layout. Per-row activation scales are the served config: there the
+    epilogue leaves the kernel for a (rows, N) scale."""
+    cfg = dataclasses.replace(_CFG, use_kernel=use_kernel, **SCALES[scales])
     x = jnp.asarray(rng.normal(0, 1, (4, 8, 96)).astype(np.float32))
-    w = jnp.asarray(rng.normal(0, 0.1, (96, 16)).astype(np.float32))
-    pw = prepare_weight(w, _CFG)
-    for cfg in (_CFG,                                     # unfused kernel
-                dataclasses.replace(_CFG, fused=True),    # fused kernel
-                dataclasses.replace(_CFG, use_kernel=False)):  # emulation
-        raw = np.asarray(qmatmul(x, w, cfg))
-        prep = np.asarray(qmatmul(x, pw, cfg))
-        np.testing.assert_array_equal(raw, prep)
-
-
-def test_prepared_per_channel(rng):
-    cfg = dataclasses.replace(_CFG, per_channel=True, fused=True)
-    x = jnp.asarray(rng.normal(0, 1, (8, 96)).astype(np.float32))
     w = jnp.asarray(rng.normal(0, 0.1, (96, 16)).astype(np.float32))
     pw = prepare_weight(w, cfg)
     np.testing.assert_array_equal(np.asarray(qmatmul(x, w, cfg)),
@@ -241,9 +140,11 @@ def test_prepared_values_roundtrip(rng):
     qt = quantize_fp8(w, _F, margin=1.0)
     np.testing.assert_array_equal(np.asarray(pw.values()), np.asarray(qt.q))
     np.testing.assert_allclose(np.asarray(pw.scale), np.asarray(qt.scale))
-    # limb planes reconstruct the same fixed-point integers
-    np.testing.assert_array_equal(np.asarray(pw.limbs),
-                                  np.asarray(limb_decompose(qt.q, _F)))
+    np.testing.assert_array_equal(np.asarray(pw.codes),
+                                  np.asarray(formats.encode_bits(qt.q, _F)))
+    # the planner's weight sigma is the std of the same fixed-point limbs
+    want = float(jnp.std(limb_decompose(qt.q, _F).astype(jnp.float32)))
+    assert pw.limb_sigma == pytest.approx(want, rel=1e-6)
 
 
 def test_prepared_stacked_scan_slices(rng):
@@ -252,7 +153,7 @@ def test_prepared_stacked_scan_slices(rng):
     ws = jnp.asarray(rng.normal(0, 0.1, (3, 96, 4, 4)).astype(np.float32))
     pws = prepare_weight(ws, _CFG, stacked=True)
     assert pws.codes.shape == (3, 96, 16)
-    assert pws.limbs.shape == (3, 3, 96, 16)
+    assert pws.scale.shape == (3,)
     assert pws.tail == (4, 4)
 
     def body(c, pw_slice):
@@ -299,25 +200,22 @@ def test_prepare_params_converts_only_proj_weights(rng):
 
 
 def test_fused_config_prepare_drops_limb_planes(rng):
-    """A fused-config PreparedWeight keeps only the packed codes (the
-    3-byte/elem limb planes would be dead memory); consumers that want
-    limbs fall back to decoding the codes."""
-    cfg_fused = dataclasses.replace(_CFG, fused=True)
+    """A PreparedWeight holds the packed codes and the scale, nothing
+    else: the kernel decodes limbs per tile, the emulation path decodes
+    values from the codes, so one build serves both."""
     x = jnp.asarray(rng.normal(0, 1, (8, 96)).astype(np.float32))
     w = jnp.asarray(rng.normal(0, 0.1, (96, 16)).astype(np.float32))
-    pw = prepare_weight(w, cfg_fused)
-    assert pw.limbs is None
-    assert pw.codes is not None
+    pw = prepare_weight(w, _CFG)
+    leaves = jax.tree_util.tree_leaves(pw)
+    assert [leaf.dtype for leaf in leaves] == [jnp.uint8, jnp.float32]
+    assert leaves[0] is pw.codes and leaves[1] is pw.scale
     assert pw.limb_sigma is not None and pw.limb_sigma > 0
-    # fused consumption streams the codes
-    np.testing.assert_array_equal(np.asarray(qmatmul(x, pw, cfg_fused)),
-                                  np.asarray(qmatmul(x, w, cfg_fused)))
-    # unfused consumption of the limb-less weight falls back to values
-    np.testing.assert_array_equal(np.asarray(qmatmul(x, pw, _CFG)),
-                                  np.asarray(qmatmul(x, w, _CFG)))
-    # emulation-path prepare (use_kernel=False) also keeps codes only
-    pw_emu = prepare_weight(w, dataclasses.replace(_CFG, use_kernel=False))
-    assert pw_emu.limbs is None
+    # the emulation-path config is served by the very same build
+    cfg_emu = dataclasses.replace(_CFG, use_kernel=False)
+    assert prepare_weight(w, cfg_emu) is pw
+    for cfg in (_CFG, cfg_emu):
+        np.testing.assert_array_equal(np.asarray(qmatmul(x, pw, cfg)),
+                                      np.asarray(qmatmul(x, w, cfg)))
 
 
 def test_prepare_cache_does_not_pin_source_weight():
@@ -347,7 +245,7 @@ def test_prepared_rejects_wrong_config(rng):
 def test_markov_flush_target_keeps_exactness(rng):
     """Markov-planned (longer) flush periods must not change results on
     layer-sized problems (class sums stay in f32-exact range)."""
-    cfg = dataclasses.replace(_CFG, fused=True, flush_target=1e-6)
+    cfg = dataclasses.replace(_CFG, flush_target=1e-6)
     x = jnp.asarray(rng.normal(0, 1, (8, 256)).astype(np.float32))
     w = jnp.asarray(rng.normal(0, 0.1, (256, 16)).astype(np.float32))
     np.testing.assert_array_equal(

@@ -38,7 +38,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 _CFG_NONE = QuantConfig()
 _CFG_FP8 = QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
                        block_m=32, block_n=32, block_k=32)
-_CFG_KERNEL = dataclasses.replace(_CFG_FP8, use_kernel=True, fused=True)
+_CFG_KERNEL = dataclasses.replace(_CFG_FP8, use_kernel=True)
 
 # (spec, x shape, w shape) — every model call-site family:
 # plain proj, attention out-proj (multi-axis K), MoE router, MoE expert
@@ -309,7 +309,7 @@ _ENGINE_SETUP = """
 
     cfg = dataclasses.replace(reduced_config("deepseek-7b"),
         quant=QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                          use_kernel=True, fused=True,
+                          use_kernel=True,
                           block_m=32, block_n=32, block_k=32))
     params, dims = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)

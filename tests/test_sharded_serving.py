@@ -40,7 +40,7 @@ _SETUP = """
 
     cfg = reduced_config("deepseek-7b")
     cfg = dataclasses.replace(cfg, quant=QuantConfig(
-        dtype="fp8_e4m3", accum="mgs_exact", use_kernel=True, fused=True,
+        dtype="fp8_e4m3", accum="mgs_exact", use_kernel=True,
         block_m=32, block_n=32, block_k=32))
     params, dims = init_params(cfg, jax.random.PRNGKey(0))
 """
@@ -165,7 +165,7 @@ def test_moe_sharded_bit_identity_top_k3():
     cfg = dataclasses.replace(
         reduced_config("granite-moe-1b-a400m"), top_k=3,
         quant=QuantConfig(dtype="fp8_e4m3", accum="mgs_exact",
-                          use_kernel=True, fused=True,
+                          use_kernel=True,
                           block_m=32, block_n=32, block_k=32))
     params, dims = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -215,7 +215,7 @@ def test_native_sharded_prepare_matches_local():
     from repro.quant import QuantConfig, prepare_weight
 
     qc = QuantConfig(dtype="fp8_e4m3", accum="mgs_exact", use_kernel=True,
-                     fused=True, per_channel=True)
+                     per_channel=True)
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(0, 0.1, (2, 64, 8, 16)).astype(np.float32))
     mesh = make_serve_mesh()

@@ -114,37 +114,33 @@ def test_size_one_axes_canonicalized_away():
 
 def test_prepared_plane_dims_uses_leading_tail_dim():
     rules = make_rules(_mesh(), "serve")
-    codes_d, limbs_d, out_d = prepared_plane_dims(
+    codes_d, out_d = prepared_plane_dims(
         ("layers", "embed", "heads", "head_dim"), rules, stacked=True)
     assert out_d == "heads"                      # leading tail dim
     assert codes_d == ("layers", "embed", "heads")
-    assert limbs_d == ("layers", None, "embed", "heads")
     # unstacked FFN weight: single tail dim
-    codes_d, limbs_d, out_d = prepared_plane_dims(("embed", "ffn"), rules)
+    codes_d, out_d = prepared_plane_dims(("embed", "ffn"), rules)
     assert (codes_d, out_d) == (("embed", "ffn"), "ffn")
-    assert limbs_d == (None, "embed", "ffn")
     # a candidate-less leading tail dim never falls through to later
     # dims: sharding the flat axis by a trailing dim would cut across
     # leading-dim slices
-    _, _, out_d = prepared_plane_dims(("embed", "head_dim", "heads"),
-                                      rules)
+    _, out_d = prepared_plane_dims(("embed", "head_dim", "heads"), rules)
     assert out_d is None
 
 
 def test_prepared_specs_planes():
-    """codes/limbs share the weight's (in, out) layout; per-channel scales
-    follow the out dim; the limb-plane axis stays local."""
+    """codes take the weight's (in, out) layout; per-channel scales
+    follow the out dim."""
     rules = make_rules(_mesh((4, 2), ("data", "model")), "serve")
     w_dims = ("layers", "embed", "heads", "head_dim")
     w_shape = (4, 64, 8, 16)                     # codes (4, 64, 128)
-    codes, limbs, scale = prepared_specs(w_dims, w_shape, rules,
-                                         stacked=True, per_channel=True)
+    codes, scale = prepared_specs(w_dims, w_shape, rules, stacked=True,
+                                  per_channel=True)
     assert codes == P(None, "data", "model")
-    assert limbs == P(None, None, "data", "model")
     assert scale == P(None, None, "model")
     # per-tensor scale: one scalar per layer slice, replicated
-    _, _, scale_pt = prepared_specs(w_dims, w_shape, rules, stacked=True,
-                                    per_channel=False)
+    _, scale_pt = prepared_specs(w_dims, w_shape, rules, stacked=True,
+                                 per_channel=False)
     assert scale_pt == P()
 
 
@@ -152,10 +148,9 @@ def test_prepared_specs_divisibility_fallback():
     """An out dim that does not divide the mesh axis replicates, exactly
     like the raw weight would."""
     rules = make_rules(_mesh((2, 8), ("data", "model")), "serve")
-    codes, limbs, _ = prepared_specs(("embed", "heads", "head_dim"),
-                                     (64, 3, 7), rules)   # heads=3, model=8
+    codes, _ = prepared_specs(("embed", "heads", "head_dim"),
+                              (64, 3, 7), rules)   # heads=3, model=8
     assert codes == P("data")
-    assert limbs == P(None, "data")
 
 
 def test_prepared_specs_never_shard_mid_head():
@@ -163,10 +158,10 @@ def test_prepared_specs_never_shard_mid_head():
     size: heads=4 on model=8 replicates even though n = 4*16 = 64 is
     divisible — a shard must never cut across a head boundary."""
     rules = make_rules(_mesh((2, 8), ("data", "model")), "serve")
-    codes, _, _ = prepared_specs(("embed", "heads", "head_dim"),
-                                 (64, 4, 16), rules)
+    codes, _ = prepared_specs(("embed", "heads", "head_dim"),
+                              (64, 4, 16), rules)
     assert codes == P("data")                    # out axis replicated
     # divisible head count shards head-aligned
-    codes, _, _ = prepared_specs(("embed", "heads", "head_dim"),
-                                 (64, 8, 16), rules)
+    codes, _ = prepared_specs(("embed", "heads", "head_dim"),
+                              (64, 8, 16), rules)
     assert codes == P("data", "model")

@@ -40,8 +40,7 @@ from repro.quant.calibrate import _LIMB_LO, _N_LEVELS
 
 def _quant(**kw):
     base = dict(dtype="fp8_e4m3", accum="mgs_exact", use_kernel=True,
-                fused=True, flush_target=1e-6,
-                block_m=32, block_n=32, block_k=32)
+                flush_target=1e-6, block_m=32, block_n=32, block_k=32)
     base.update(kw)
     return QuantConfig(**base)
 
@@ -297,23 +296,26 @@ def test_runtime_flush_period_no_retrace_and_bitwise():
 
     from repro.core import formats
     from repro.kernels import ops, ref
-    from repro.kernels.mgs_matmul import mgs_matmul_exact_pallas
+    from repro.kernels.mgs_matmul import mgs_matmul_exact_fused_pallas
 
     rng = np.random.default_rng(0)
     x = jnp.asarray(formats.round_to_format(
         rng.standard_normal((8, 256)).astype(np.float32), formats.E4M3))
     w = jnp.asarray(formats.round_to_format(
         rng.standard_normal((256, 8)).astype(np.float32), formats.E4M3))
+    xc = formats.encode_bits(x, formats.E4M3)
+    wc = formats.encode_bits(w, formats.E4M3)
 
     def run(fp):
-        return mgs_matmul_exact_pallas(x, w, formats.E4M3, block_m=8,
-                                       block_n=8, block_k=64,
-                                       flush_period=fp, interpret=True)
+        return mgs_matmul_exact_fused_pallas(xc, wc, formats.E4M3,
+                                             block_m=8, block_n=8,
+                                             block_k=64, flush_period=fp,
+                                             interpret=True)
 
     static = run(2)
-    n0 = mgs_matmul_exact_pallas._cache_size()
+    n0 = mgs_matmul_exact_fused_pallas._cache_size()
     runtime = run(jnp.asarray(2, jnp.int32))
-    n1 = mgs_matmul_exact_pallas._cache_size()
+    n1 = mgs_matmul_exact_fused_pallas._cache_size()
     # same *value* as a runtime operand: bit-identical to the static plan
     assert np.asarray(runtime).tobytes() == np.asarray(static).tobytes()
     for fp in (1, 3, 4, 3337578147):
@@ -323,7 +325,7 @@ def test_runtime_flush_period_no_retrace_and_bitwise():
             np.asarray(ref.mgs_matmul_ref(x, w, formats.E4M3, "exact")),
             rtol=1e-6)
     # swapping the period value is an operand change, never a retrace
-    assert mgs_matmul_exact_pallas._cache_size() == n1
+    assert mgs_matmul_exact_fused_pallas._cache_size() == n1
     assert n1 <= n0 + 1   # at most the one new int32-operand entry
 
     # the public wrapper clamps oversized *python* periods pre-jit (the

@@ -22,9 +22,8 @@ from repro.configs import get_config
 from repro.kernels import mgs_attention, ops
 from repro.kernels.mgs_attention import (mgs_paged_flash_attention,
                                          mgs_paged_verify_attention)
-from repro.kernels.mgs_matmul import (WS_STRIPE_BUDGET_BYTES, fused_tiles,
-                                      mgs_matmul_exact_fused_pallas,
-                                      ws_stripe_bytes)
+from repro.kernels.mgs_matmul import (fused_tiles,
+                                      mgs_matmul_exact_fused_pallas)
 from repro.models import init_params
 from repro.models.transformer import decode_step_paged, init_paged_cache
 from repro.quant import prepare_logits_head, prepare_params
@@ -69,23 +68,42 @@ def _compile(fn, *shapes):
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
-@pytest.mark.parametrize("schedule", ["output", "weight", "activation"])
+@pytest.mark.parametrize("variant", ["plain", "runtime-flush",
+                                     "epilogue-silu", "epilogue-gelu"])
 @pytest.mark.parametrize("m", [8, 512], ids=["decode", "prefill"])
 @pytest.mark.parametrize("k,n", [(D_MODEL, D_MODEL), (D_MODEL, D_FF),
                                  (D_FF, D_MODEL)], ids=["wq", "wg", "wd"])
-def test_fused_matmul_compiles(one_chip, schedule, m, k, n):
-    """The output-stationary schedule at the tiles ``ops.mgs_matmul``
-    gives it (``fused_tiles``); the stationary ones at 128x128x128."""
-    tiles = {}
-    if schedule == "output":
-        tiles = dict(zip(("block_m", "block_n", "block_k"),
-                         fused_tiles(m, k, n)))
+def test_fused_matmul_compiles(one_chip, variant, m, k, n):
+    """The exact kernel at the tiles ``ops.mgs_matmul`` gives it
+    (``fused_tiles``): plain; under a traced int32 flush period with the
+    caller's 128-wide K tile, the program a calibration hot swap serves;
+    and with the per-tensor presets' in-kernel epilogue, a (1, N) scale,
+    a bias and the activation."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    flush_block_k = 128 if variant == "runtime-flush" else None
+    tiles = dict(zip(("block_m", "block_n", "block_k"),
+                     fused_tiles(m, k, n, flush_block_k=flush_block_k)))
+    operands = [shape((m, k), jnp.uint8), shape((k, n), jnp.uint8)]
+    if variant == "runtime-flush":
+        operands.append(shape((), jnp.int32))
 
-    def fn(x, w):
-        return mgs_matmul_exact_fused_pallas(x, w, schedule=schedule,
-                                             interpret=False, **tiles)
-    _compile(fn, jax.ShapeDtypeStruct((m, k), jnp.uint8, sharding=one_chip),
-             jax.ShapeDtypeStruct((k, n), jnp.uint8, sharding=one_chip))
+        def fn(x, w, fp):
+            return mgs_matmul_exact_fused_pallas(
+                x, w, flush_period=fp, interpret=False, **tiles)
+    elif variant.startswith("epilogue-"):
+        operands += [shape((1, n), jnp.float32), shape((n,), jnp.float32)]
+
+        def fn(x, w, scale, bias):
+            return mgs_matmul_exact_fused_pallas(
+                x, w, scale=scale, bias=bias,
+                activation=variant.removeprefix("epilogue-"),
+                interpret=False, **tiles)
+    else:
+        def fn(x, w):
+            return mgs_matmul_exact_fused_pallas(x, w, interpret=False,
+                                                 **tiles)
+    _compile(fn, *operands)
 
 
 @pytest.mark.parametrize("m,k,n", [
@@ -98,16 +116,7 @@ def test_fused_matmul_compiles_at_chosen_tiles(one_chip, m, k, n):
     """The cell's decode calls (32 slot rows), the logits head at decode
     and at batch-1 prefill, and the largest prefill bucket, each at the
     tiles ``fused_tiles`` gives."""
-    test_fused_matmul_compiles(one_chip, "output", m, k, n)
-
-
-@pytest.mark.parametrize("schedule", ["weight", "activation"])
-def test_stationary_stripe_at_budget_compiles(one_chip, schedule):
-    """The largest K whose K-resident limb stripe the budget admits fits
-    the chip's scoped VMEM (the compiler refuses about twice that)."""
-    k = WS_STRIPE_BUDGET_BYTES // (3 * 128) // 128 * 128
-    assert ws_stripe_bytes(k, 128, 128) <= WS_STRIPE_BUDGET_BYTES
-    test_fused_matmul_compiles(one_chip, schedule, 512, k, D_MODEL)
+    test_fused_matmul_compiles(one_chip, "plain", m, k, n)
 
 
 def _paged_operands(one_chip, t):
